@@ -30,7 +30,10 @@ bench:
 # KV preemption vs the shed-only FIFO baseline, quantized-vs-bf16 KV pool)
 bench-smoke:
 	BENCH_SMOKE=1 PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run \
-		--only serve_prefix_reuse,serve_mixed_tick,serve_speculative,serve_multi_model,serve_overload,serve_kv_quant,serve_replica_scaling
+		--only serve_prefix_reuse,serve_mixed_tick,serve_speculative,serve_multi_model,serve_overload,serve_kv_quant
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+		BENCH_SMOKE=1 PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run \
+		--only serve_replica_scaling
 
 # exactly what CI's chaos-smoke job runs: a seeded fault schedule (replica
 # crash + KV migration, transient submit errors, slow ticks) over the

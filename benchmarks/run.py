@@ -19,6 +19,9 @@ def main() -> None:
                     help="comma-separated substring filter on benchmark name")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import fastpath, kv_store, pipelines, roofline, serve
 
     benches = [

@@ -925,11 +925,27 @@ def bench_serve_kv_quant(out) -> dict:
 # ----------------------------------------------------------------------
 # Replica scaling on mesh slices
 # ----------------------------------------------------------------------
-def _replica_scaling_measure() -> dict:
-    """Measure 1-slice vs 2-slice deployments (needs >= 4 local devices;
-    ``bench_serve_replica_scaling`` re-execs under a forced device count
-    when the session has fewer).  Returns the raw per-arm results — all
-    asserting happens in the parent."""
+def bench_serve_replica_scaling(out) -> dict:
+    """2 replicas on 2 DISJOINT mesh slices vs 1 replica on 1 slice, same
+    workload: per-driver-pass token throughput must scale near-linearly
+    (each pass ticks every busy engine once; with the work split across two
+    slices each engine drains in about half the passes).  Wall-clock 2x
+    needs the data-parallel tick drivers tracked in ROADMAP item 1 — the
+    single-threaded round-robin driver serializes the two slices' ticks, so
+    this benchmark asserts the per-pass ratio plus REAL sharded placement:
+    disjoint 2-device slices, zero donate misses (sharded publishes stay
+    zero-copy), and host_syncs == ticks per engine.
+
+    Needs 4 local devices and runs on the ones it finds: on a CPU host,
+    force them before Python starts (``make bench-smoke`` sets
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``)."""
+    n_dev = len(jax.devices())
+    if n_dev < 4:
+        raise RuntimeError(
+            f"serve_replica_scaling needs 4 devices (2 slices x 2) but "
+            f"found {n_dev} {jax.devices()[0].platform} device(s); on a "
+            f"CPU host set XLA_FLAGS=--xla_force_host_platform_device_"
+            f"count=8 before starting Python")
     from repro.models import init_params
     from repro.models.config import ModelConfig
     from repro.serving.cluster import ServeCluster
@@ -984,50 +1000,6 @@ def _replica_scaling_measure() -> dict:
                 "donate_misses": c.kv_store.donate_misses,
                 "pool_devices": pool_dev_sets,
             }
-    return results
-
-
-def bench_serve_replica_scaling(out) -> dict:
-    """2 replicas on 2 DISJOINT mesh slices vs 1 replica on 1 slice, same
-    workload: per-driver-pass token throughput must scale near-linearly
-    (each pass ticks every busy engine once; with the work split across two
-    slices each engine drains in about half the passes).  Wall-clock 2x
-    needs the data-parallel tick drivers tracked in ROADMAP item 1 — the
-    single-threaded round-robin driver serializes the two slices' ticks, so
-    this benchmark asserts the per-pass ratio plus REAL sharded placement:
-    disjoint 2-device slices, zero donate misses (sharded publishes stay
-    zero-copy), and host_syncs == ticks per engine."""
-    import json as _json
-    import subprocess
-    import sys
-
-    if len(jax.devices()) >= 4:
-        results = _replica_scaling_measure()
-    else:
-        # jax is already initialized single-device here: re-exec a child
-        # with the forced device count (the flag must precede first init).
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8").strip()
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(root, "src"), root]
-            + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-        code = ("import json\n"
-                "from benchmarks.serve import _replica_scaling_measure\n"
-                "print('RSJSON:' + json.dumps(_replica_scaling_measure()))\n")
-        proc = subprocess.run([sys.executable, "-c", code], cwd=root,
-                              env=env, capture_output=True, text=True,
-                              timeout=1800)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"forced-device child failed:\n{proc.stdout}\n{proc.stderr}")
-        line = [ln for ln in proc.stdout.splitlines()
-                if ln.startswith("RSJSON:")][-1]
-        results = _json.loads(line[len("RSJSON:"):])
-        out(f"# measured in forced-8-device child (parent had "
-            f"{len(jax.devices())} device(s))")
 
     base, shard = results["baseline"], results["sharded"]
     for arm in (base, shard):

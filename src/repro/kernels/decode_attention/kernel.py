@@ -363,7 +363,7 @@ def ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables, row_ids,
     )
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=(dimension_semantics
                                  or DEFAULT_DIMENSION_SEMANTICS))
     out = pl.pallas_call(

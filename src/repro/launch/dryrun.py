@@ -2,10 +2,13 @@
 production mesh, prove memory fit, and extract roofline terms.
 
 MUST set the placeholder device count before ANY jax import (jax locks the
-device count on first init) — hence the first two lines.
+device count on first init) — hence the first lines.  The platform is pinned
+to the CPU in the same place, so this lowering tool (and every per-cell child
+it starts, which inherits the environment) never claims an accelerator.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

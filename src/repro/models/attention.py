@@ -7,6 +7,11 @@ Three execution backends:
 - ``pallas``           — the Pallas TPU kernel (kernels/flash_attention).
 - ``pallas_interpret`` — same kernel, interpret mode (CPU validation).
 
+``cfg.attn_backend = None`` (the default) lets the platform choose for the
+paged tick (``paged_attn_backend``): the compiled ragged kernel on a TPU,
+the XLA gather path elsewhere; unpinned full-sequence attention stays on
+``xla``.  Interpret mode is a CPU test tool and raises on a TPU.
+
 Cache layout: ``{"k": (B, S_c, K, D), "v": (B, S_c, K, D), "pos": (B, S_c)}``
 where ``pos`` holds the absolute position stored in each slot (-1 = empty).
 Local-window layers use a ring buffer (S_c = window); the pos array makes
@@ -236,14 +241,16 @@ def paged_pool_axes(cfg: ModelConfig, kv_dtype: str | None = None) -> dict:
     return axes
 
 
-def _dequant_pool_leaves(pool: dict):
-    """f32 K/V leaves for the XLA densify fallback (identity when the pool
-    is unquantized).  The fallback materializes a dequantized pool copy —
-    acceptable off-TPU; the Pallas path dequantizes in-register instead."""
+def _dequant_pool_leaves(pool: dict, dtype):
+    """K/V leaves at the model's activation ``dtype`` for the XLA densify
+    fallback (identity when the pool is unquantized), so attention returns
+    the dtype it was given, as the kernel does.  The fallback materializes
+    a dequantized pool copy — acceptable off-TPU; the Pallas path
+    dequantizes in-register instead."""
     if "k_scale" not in pool:
         return pool["k"], pool["v"]
-    return (da_quant.dequantize_kv(pool["k"], pool["k_scale"]),
-            da_quant.dequantize_kv(pool["v"], pool["v_scale"]))
+    return (da_quant.dequantize_kv(pool["k"], pool["k_scale"]).astype(dtype),
+            da_quant.dequantize_kv(pool["v"], pool["v_scale"]).astype(dtype))
 
 
 def _quantize_for_pool(pool: dict, k_new, v_new):
@@ -303,6 +310,63 @@ def _ragged_paged_write(pool: dict, k_new, v_new, positions, block_table,
     return out
 
 
+# ---------------------------------------------------------------- backends
+def _checked_backend(backend: str) -> str:
+    if backend == "pallas_interpret" and jax.default_backend() == "tpu":
+        raise ValueError(
+            "attn_backend='pallas_interpret' runs the Pallas kernels in the "
+            "CPU interpreter; on a TPU leave attn_backend unset (or 'pallas') "
+            "so the compiled kernel runs")
+    return backend
+
+
+def paged_attn_backend(cfg: ModelConfig) -> str:
+    """The paged tick's attention backend: ``cfg.attn_backend`` when pinned,
+    else the platform's — the compiled ragged kernel on a TPU, the XLA
+    densify path on every other backend."""
+    if cfg.attn_backend is not None:
+        return _checked_backend(cfg.attn_backend)
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _ragged_kernel_attention(q, pool: dict, block_table, row_ids, positions,
+                             *, window, cap, scale, interpret: bool):
+    """The ragged paged kernel over packed tokens q (T,H,D).
+
+    Inside a mesh context (a mesh-sliced replica's tick) the call runs under
+    ``shard_map``: Mosaic kernels cannot be partitioned automatically.  Each
+    shard runs the kernel on its own kv heads and the query heads that read
+    them — 'model' splits heads and kv_heads into contiguous equal runs, so
+    shard s's H/m query heads are exactly the G-groups of its K/m kv heads.
+    Block table, row ids and positions are replicated.  When kv_heads does
+    not divide over 'model' the pool is replicated and so is the call."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.decode_attention import ops as da_ops
+
+    names = [n for n in ("k", "v", "k_scale", "v_scale") if n in pool]
+
+    def call(q, bt, rows, pos, *leaves):
+        p = dict(zip(names, leaves))
+        return da_ops.ragged_paged_attention(
+            q, p["k"], p["v"], bt, rows, pos, k_scale=p.get("k_scale"),
+            v_scale=p.get("v_scale"), window=window, softcap=cap,
+            scale=scale, interpret=interpret)
+
+    args = (q, block_table, row_ids, positions, *(pool[n] for n in names))
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return call(*args)
+    m = dict(mesh.shape).get("model", 1)
+    ax = "model" if pool["k"].shape[2] % m == 0 else None
+    leaf = {"k": P(None, None, ax, None), "v": P(None, None, ax, None),
+            "k_scale": P(None, None, ax), "v_scale": P(None, None, ax)}
+    q_spec = P(None, ax, None)
+    in_specs = (q_spec, P(), P(), P(), *(leaf[n] for n in names))
+    return jax.shard_map(call, in_specs=in_specs, out_specs=q_spec,
+                         check_vma=False)(*args)
+
+
 # ------------------------------------------------------------------- apply
 def _qkv(params: dict, x: jax.Array, positions: jax.Array, *,
          cfg: ModelConfig, spec: LayerSpec):
@@ -337,7 +401,7 @@ def attention(params: dict, x: jax.Array, positions: jax.Array, *,
         out = _attend(q, cache["k"], cache["v"], positions, cache["pos"],
                       window=spec.window, cap=cap, scale=scale)
     else:
-        backend = cfg.attn_backend
+        backend = _checked_backend(cfg.attn_backend or "xla")
         if backend in ("pallas", "pallas_interpret"):
             from repro.kernels.flash_attention import ops as fa_ops
             out = fa_ops.flash_attention(
@@ -379,8 +443,9 @@ def paged_attention(params: dict, x: jax.Array, positions: jax.Array, *,
     Batched mode (``row_ids is None``, x row b ↔ block_table row b):
     - suffix prefill (T = S - reused_len): tokens enter at positions starting
       past the reused prefix and attend to the cached prefix KV for free;
-    - decode (T = 1): the Pallas block-gather kernel when cfg.attn_backend is
-      pallas/pallas_interpret, else an XLA gather + masked softmax.
+    - decode (T = 1): the Pallas block-gather kernel when the backend
+      (``paged_attn_backend``) is pallas/pallas_interpret, else an XLA
+      gather + masked softmax.
 
     Ragged mode (``row_ids`` given): x is ONE packed row (B = 1) of mixed
     prefill-chunk and decode tokens — the engine's unified token-budget tick.
@@ -395,21 +460,18 @@ def paged_attention(params: dict, x: jax.Array, positions: jax.Array, *,
     q, k, v = _qkv(params, x, positions, cfg=cfg, spec=spec)
     scale = D ** -0.5
     cap = cfg.attn_logit_softcap
-    backend = cfg.attn_backend
+    backend = paged_attn_backend(cfg)
     if row_ids is not None:
         pool = _ragged_paged_write(pool, k[0], v[0], positions[0],
                                    block_table, row_ids)
         if backend in ("pallas", "pallas_interpret"):
-            from repro.kernels.decode_attention import ops as da_ops
-            out = da_ops.ragged_paged_attention(
-                q[0], pool["k"], pool["v"], block_table, row_ids,
-                positions[0], k_scale=pool.get("k_scale"),
-                v_scale=pool.get("v_scale"), window=spec.window,
-                softcap=cap, scale=scale,
+            out = _ragged_kernel_attention(
+                q[0], pool, block_table, row_ids, positions[0],
+                window=spec.window, cap=cap, scale=scale,
                 interpret=(backend == "pallas_interpret"))[None]
         else:
             from repro.kernels.decode_attention.ref import densify_pool
-            kp, vp = _dequant_pool_leaves(pool)
+            kp, vp = _dequant_pool_leaves(pool, q.dtype)
             kd, vd, kpos = densify_pool(kp, vp, block_table)
             rows = jnp.clip(row_ids, 0, block_table.shape[0] - 1)
             out = _ragged_attend_chunked(
@@ -427,7 +489,7 @@ def paged_attention(params: dict, x: jax.Array, positions: jax.Array, *,
             interpret=(backend == "pallas_interpret"))[:, None]
     else:
         from repro.kernels.decode_attention.ref import densify_pool
-        kp, vp = _dequant_pool_leaves(pool)
+        kp, vp = _dequant_pool_leaves(pool, q.dtype)
         kd, vd, kpos = densify_pool(kp, vp, block_table)
         # chunked for suffix prefill (T may approach max_len, and the full
         # (B,K,G,T,nb*bs) f32 score tensor is the dominant buffer exactly as

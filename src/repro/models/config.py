@@ -87,7 +87,9 @@ class ModelConfig:
     # --- numerics / impl ---
     optimizer: str = "adamw"            # adamw | adafactor
     dtype: str = "bfloat16"
-    attn_backend: str = "xla"           # xla | pallas | pallas_interpret
+    # None = the platform decides (paged tick: compiled Pallas on a TPU,
+    # xla elsewhere; see models.attention.paged_attn_backend)
+    attn_backend: str | None = None     # None | xla | pallas | pallas_interpret
     # Paged KV pool storage dtype: None = model dtype; "int8"/"fp8_e4m3"
     # add per-(block, slot, kv-head) f32 scale leaves and quantize-on-write
     # (kernels dequantize in-register after the block-table gather).

@@ -662,6 +662,7 @@ class ModelDeployment:
         return {
             "deployment": self.name,
             "paged": self.paged,
+            "attn_backend": self.engines[0].attn_backend,
             # KV pool precision: storage dtype knob + measured bytes per
             # token slot (K/V + scale leaves over every layer) — the number
             # the quantization win is asserted on, independent of wall-clock

@@ -81,6 +81,7 @@ and ``host_syncs == decode_ticks + prefill_batches``.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -92,6 +93,7 @@ import numpy as np
 from repro.models import (decode_step, paged_mixed_step, prefill,
                           sample_with_scores, speculative_verify,
                           supports_paged, supports_speculative)
+from repro.models.attention import paged_attn_backend
 from repro.models.config import ModelConfig
 
 from .draft import DraftSource, default_draft_source
@@ -261,6 +263,11 @@ class ServeEngine:
             get their confidence signal without the host seeing logits."""
             return sample_with_scores(logits, seed, temp)
 
+        # the backend the paged tick's attention runs on (models.attention
+        # .paged_attn_backend): the compiled kernel on a TPU unless the
+        # config pins another
+        self.attn_backend = paged_attn_backend(cfg) if self.paged else None
+
         # Paged mode donates the pool operand: the step scatters into every
         # layer's pool leaf, and without donation XLA must copy the whole
         # global block pool ((num_blocks, block_size, K, D) per layer) on
@@ -279,8 +286,12 @@ class ServeEngine:
         if self.paged:
             def _mixed(p, pools, bt, toks, pos, rows, sample_idx,
                        draft_toks, draft_len, seed):
-                logits, pools = paged_mixed_step(p, pools, bt, toks, pos,
-                                                 rows, sample_idx, cfg)
+                # a sliced tick traces under its slice's mesh, so the ragged
+                # kernel runs per shard (models.attention, shard_map)
+                with (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+                      if mesh is not None else contextlib.nullcontext()):
+                    logits, pools = paged_mixed_step(p, pools, bt, toks, pos,
+                                                     rows, sample_idx, cfg)
                 tok, n_acc, score = speculative_verify(logits, draft_toks,
                                                        draft_len, seed, temp)
                 return tok, n_acc, score, pools
@@ -747,6 +758,16 @@ class ServeEngine:
         req.slot = None
         self.stats.preemptions += 1
         self.scheduler.requeue(self.replica_id, req)
+
+    def lower_tick(self):
+        """Lower the unified tick at this engine's fixed operand shapes, with
+        nothing dispatched or donated: ``lower_tick().compile()`` gives the
+        program every tick runs (its HLO, memory analysis, compile time)."""
+        n, K, T = self.cm.n_slots, self.spec_k, self.token_budget
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        return self._mixed.lower(
+            self.params, self.cm.pools, i32(n, self.cm.max_blocks), i32(T),
+            i32(T), i32(T), i32(n, K + 1), i32(n, K), i32(n), i32())
 
     def _tick_mixed(self) -> int:
         """ONE fixed-shape mixed step: decode rows (each with up to spec_k

@@ -573,6 +573,30 @@ def test_quantized_streams_deterministic_across_backends(params, kv_dtype):
     assert eng_p.stats.host_syncs == eng_p.stats.ticks
 
 
+def test_quantized_pool_under_bf16_model_on_both_backends():
+    """An int8 pool under a bf16 model: the XLA path dequantizes to the
+    model's dtype, so attention hands the layer scan the dtype it was given
+    (as the kernel does) and both backends serve the same requests."""
+    cfg = CFG.replace(dtype="bfloat16")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    prompts = [_toks(rng, L) for L in (5, 40)]
+    scores = []
+    for backend in (None, "pallas_interpret"):
+        eng = ServeEngine(cfg.replace(attn_backend=backend), params,
+                          n_slots=2, max_len=96, block_size=16,
+                          kv_dtype="int8")
+        reqs = [Request(request_id=f"r{i}", session_key=f"s{i}", prompt=p,
+                        max_new_tokens=4) for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+        assert all(len(r.tokens) == 4 and r.error is None for r in reqs)
+        assert eng.stats.host_syncs == eng.stats.ticks
+        scores.append([r.scores[0] for r in reqs])
+    np.testing.assert_allclose(scores[0], scores[1], atol=0.05)
+
+
 def test_quantized_spill_adopt_scales_bit_exact():
     """Property test on the migration path: spill_device → host → adopt on
     a sibling manager round-trips EVERY pool leaf bit-exactly — the int8

@@ -287,3 +287,46 @@ def test_deployment_carves_disjoint_slices():
         free_before_stop = len(node._free_devices)
         cluster.dep.stop()
         assert len(node._free_devices) == free_before_stop + 4
+
+
+def test_paged_backend_follows_the_platform(monkeypatch):
+    """Unpinned, the paged tick runs the compiled kernel on a TPU and the
+    XLA path elsewhere; a pinned backend is kept; interpret mode refuses a
+    TPU."""
+    from repro.models.attention import paged_attn_backend
+
+    assert CFG.attn_backend is None
+    assert paged_attn_backend(CFG) == "xla"
+    assert paged_attn_backend(CFG.replace(attn_backend="pallas_interpret")) \
+        == "pallas_interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_attn_backend(CFG) == "pallas"
+    assert paged_attn_backend(CFG.replace(attn_backend="xla")) == "xla"
+    with pytest.raises(ValueError, match="interpret"):
+        paged_attn_backend(CFG.replace(attn_backend="pallas_interpret"))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("devices", [1, 2])
+def test_sliced_kernel_matches_single_device(devices, kv_dtype):
+    """On a mesh slice the ragged kernel runs per shard (shard_map over
+    'model', each shard on its own kv heads): greedy streams and scores
+    match the single-device engine running the same interpreted kernel."""
+    [mesh] = _slice_meshes(1, devices)
+    cfg = CFG.replace(attn_backend="pallas_interpret")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompt = np.arange(1, 12, dtype=np.int32)
+
+    base = ServeEngine(cfg, params, n_slots=2, max_len=32, kv_dtype=kv_dtype)
+    ref, expected = _greedy_stream(base, prompt, rid="base", max_new_tokens=5)
+
+    eng = ServeEngine(cfg, params, n_slots=2, max_len=32, mesh=mesh,
+                      kv_dtype=kv_dtype)
+    req, got = _greedy_stream(eng, prompt, rid="sliced", max_new_tokens=5)
+
+    assert got == expected and len(got) == 5
+    np.testing.assert_allclose(req.scores, ref.scores, atol=1e-5)
+    assert eng.stats.host_syncs == eng.stats.ticks
+    assert eng.cm.devstore.donate_misses == 0
+    if devices > 1:     # a 1-device shard_map lowers to the plain call
+        assert "sdy.manual_computation" in eng.lower_tick().as_text()

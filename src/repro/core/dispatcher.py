@@ -28,6 +28,8 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 from .objects import CascadeObject, monotonic_ns
 from .pools import DispatchPolicy
 from .trie import PathTrie
@@ -102,7 +104,9 @@ class UpcallThreadPool:
                 return
             ev.dequeued_ns = monotonic_ns()
             try:
-                ev.result = ev.handle.fn(ev.obj, ev)
+                # a host span per lambda call (see serving.spans)
+                with TraceAnnotation("cascade.upcall"):
+                    ev.result = ev.handle.fn(ev.obj, ev)
             except BaseException as e:  # surfaced to the waiter, not swallowed
                 ev.error = e
             ev.done_ns = monotonic_ns()

@@ -302,24 +302,36 @@ def ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables, row_ids,
 
     A fourth scalar-prefetch operand carries per-row live-block counts
     (``sum(block_tables >= 0, axis=1)`` — tables are dense prefixes), and
-    the kernel skips the zero-contribution tail of the nb axis per token."""
+    the kernel skips the zero-contribution tail of the nb axis per token.
+
+    The ops' metadata names two phases: ``kv_pool/layout``, the pool's
+    transposes into the kernel's (N,K,bs,D) order, and ``attention``, the
+    rest; the custom call is named ``ragged_paged_attention``."""
     T, H, D = q.shape
     N, bs, K, _ = k_pool.shape
     G = H // K
     nb = block_tables.shape[1]
     quantized = k_scale is not None
-    # -1 pads clamp to block 0 (the engine's reserved null block); their
-    # implicit positions j*bs+p exceed token_pos, so the causal mask kills
-    # them.  Pad ROWS clamp to row 0; token_pos = -1 masks every position.
-    bt = jnp.maximum(block_tables, 0).astype(jnp.int32)
-    rows = jnp.clip(row_ids, 0, block_tables.shape[0] - 1).astype(jnp.int32)
-    # Per-row live-block counts for the early-out (tables are dense
-    # prefixes: valid entries precede every -1).
-    nblk = jnp.sum(block_tables >= 0, axis=1).astype(jnp.int32)
-
-    qh = q.reshape(T, K, G, D)
-    kt = k_pool.transpose(0, 2, 1, 3)                         # (N,K,bs,D)
-    vt = v_pool.transpose(0, 2, 1, 3)
+    with jax.named_scope("attention"):
+        # -1 pads clamp to block 0 (the engine's reserved null block); their
+        # implicit positions j*bs+p exceed token_pos, so the causal mask
+        # kills them.  Pad ROWS clamp to row 0; token_pos = -1 masks every
+        # position.
+        bt = jnp.maximum(block_tables, 0).astype(jnp.int32)
+        rows = jnp.clip(row_ids, 0,
+                        block_tables.shape[0] - 1).astype(jnp.int32)
+        # Per-row live-block counts for the early-out (tables are dense
+        # prefixes: valid entries precede every -1).
+        nblk = jnp.sum(block_tables >= 0, axis=1).astype(jnp.int32)
+        qh = q.reshape(T, K, G, D)
+    with jax.named_scope("kv_pool"), jax.named_scope("layout"):
+        kt = k_pool.transpose(0, 2, 1, 3)                     # (N,K,bs,D)
+        vt = v_pool.transpose(0, 2, 1, 3)
+        # (N,bs,K) → (N,K,bs,1): a (bs, 1) tile gathered by the same block
+        # index, broadcasting over D in the kernel.
+        scales = ([k_scale.transpose(0, 2, 1)[..., None],
+                   v_scale.transpose(0, 2, 1)[..., None]] if quantized
+                  else [])
 
     kern = functools.partial(_ragged_kernel, scale=scale, softcap=softcap,
                              window=window, block_size=bs,
@@ -334,10 +346,8 @@ def ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables, row_ids,
                      lambda t, h, j, rows, bt, qp, nblk:
                      (bt[rows[t], j], h, 0, 0)),                         # v
     ]
-    operands = [qh, kt, vt]
+    operands = [qh, kt, vt, *scales]
     if quantized:
-        # (N,bs,K) → (N,K,bs,1): a (bs, 1) tile gathered by the same block
-        # index, broadcasting over D in the kernel.
         in_specs += [
             pl.BlockSpec((None, None, bs, 1),
                          lambda t, h, j, rows, bt, qp, nblk:
@@ -346,8 +356,6 @@ def ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables, row_ids,
                          lambda t, h, j, rows, bt, qp, nblk:
                          (bt[rows[t], j], h, 0, 0)),
         ]
-        operands += [k_scale.transpose(0, 2, 1)[..., None],
-                     v_scale.transpose(0, 2, 1)[..., None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                        # rows, bt, qp, nblk
         grid=(T, K, nb),
@@ -366,12 +374,13 @@ def ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables, row_ids,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=(dimension_semantics
                                  or DEFAULT_DIMENSION_SEMANTICS))
-    out = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),
-        interpret=interpret, **kwargs,
-    )(rows, bt, token_pos.astype(jnp.int32), nblk, *operands)
-    return out.reshape(T, H, D)
+    with jax.named_scope("attention"):
+        out = pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),
+            interpret=interpret, name="ragged_paged_attention", **kwargs,
+        )(rows, bt, token_pos.astype(jnp.int32), nblk, *operands)
+        return out.reshape(T, H, D)
 
 
 def paged_decode_attention_fwd(q, k_pool, v_pool, block_tables, q_pos, *,

@@ -457,30 +457,39 @@ def paged_attention(params: dict, x: jax.Array, positions: jax.Array, *,
     """
     B, T, _ = x.shape
     K, D = cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _qkv(params, x, positions, cfg=cfg, spec=spec)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(params, x, positions, cfg=cfg, spec=spec)
     scale = D ** -0.5
     cap = cfg.attn_logit_softcap
     backend = paged_attn_backend(cfg)
+    kernel = backend in ("pallas", "pallas_interpret")
     if row_ids is not None:
-        pool = _ragged_paged_write(pool, k[0], v[0], positions[0],
-                                   block_table, row_ids)
-        if backend in ("pallas", "pallas_interpret"):
+        with jax.named_scope("kv_pool"), jax.named_scope("write"):
+            pool = _ragged_paged_write(pool, k[0], v[0], positions[0],
+                                       block_table, row_ids)
+        if kernel:
+            # the kernel names its own phases: the pool's layout, then
+            # attention
             out = _ragged_kernel_attention(
                 q[0], pool, block_table, row_ids, positions[0],
                 window=spec.window, cap=cap, scale=scale,
                 interpret=(backend == "pallas_interpret"))[None]
         else:
-            from repro.kernels.decode_attention.ref import densify_pool
-            kp, vp = _dequant_pool_leaves(pool, q.dtype)
-            kd, vd, kpos = densify_pool(kp, vp, block_table)
-            rows = jnp.clip(row_ids, 0, block_table.shape[0] - 1)
-            out = _ragged_attend_chunked(
-                q[0], kd, vd, kpos, positions[0], rows, window=spec.window,
-                cap=cap, scale=scale, q_chunk=cfg.q_chunk)[None]
-        y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
+            with jax.named_scope("attention"):
+                from repro.kernels.decode_attention.ref import densify_pool
+                kp, vp = _dequant_pool_leaves(pool, q.dtype)
+                kd, vd, kpos = densify_pool(kp, vp, block_table)
+                rows = jnp.clip(row_ids, 0, block_table.shape[0] - 1)
+                out = _ragged_attend_chunked(
+                    q[0], kd, vd, kpos, positions[0], rows,
+                    window=spec.window, cap=cap, scale=scale,
+                    q_chunk=cfg.q_chunk)[None]
+        with jax.named_scope("attention"):
+            y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
         return y, pool
-    pool = _paged_write(pool, k, v, positions, block_table)
-    if T == 1 and backend in ("pallas", "pallas_interpret"):
+    with jax.named_scope("kv_pool"), jax.named_scope("write"):
+        pool = _paged_write(pool, k, v, positions, block_table)
+    if T == 1 and kernel:
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.paged_decode_attention(
             q[:, 0], pool["k"], pool["v"], block_table, positions[:, 0],
@@ -488,13 +497,17 @@ def paged_attention(params: dict, x: jax.Array, positions: jax.Array, *,
             window=spec.window, softcap=cap, scale=scale,
             interpret=(backend == "pallas_interpret"))[:, None]
     else:
-        from repro.kernels.decode_attention.ref import densify_pool
-        kp, vp = _dequant_pool_leaves(pool, q.dtype)
-        kd, vd, kpos = densify_pool(kp, vp, block_table)
-        # chunked for suffix prefill (T may approach max_len, and the full
-        # (B,K,G,T,nb*bs) f32 score tensor is the dominant buffer exactly as
-        # in dense prefill); decode's T=1 short-circuits to plain _attend
-        out = _attend_chunked(q, kd, vd, positions, kpos, window=spec.window,
-                              cap=cap, scale=scale, q_chunk=cfg.q_chunk)
-    y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
+        with jax.named_scope("attention"):
+            from repro.kernels.decode_attention.ref import densify_pool
+            kp, vp = _dequant_pool_leaves(pool, q.dtype)
+            kd, vd, kpos = densify_pool(kp, vp, block_table)
+            # chunked for suffix prefill (T may approach max_len, and the
+            # full (B,K,G,T,nb*bs) f32 score tensor is the dominant buffer
+            # exactly as in dense prefill); decode's T=1 short-circuits to
+            # plain _attend
+            out = _attend_chunked(q, kd, vd, positions, kpos,
+                                  window=spec.window, cap=cap, scale=scale,
+                                  q_chunk=cfg.q_chunk)
+    with jax.named_scope("attention"):
+        y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
     return y, pool

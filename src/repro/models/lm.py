@@ -193,8 +193,10 @@ def _apply_block(block_params, x, positions, *, cfg: ModelConfig,
         x = x + _barrier(mlp(p["mlp"], rmsnorm(p["norm_mlp"], v)), cfg)
         return x, new_cache, aux
 
-    # attn_mlp / attn_moe
-    h = rmsnorm(block_params["norm_attn"], x)
+    # attn_mlp / attn_moe; the scopes name the paged tick's phases in its
+    # ops' metadata (the paged path names qkv, kv_pool and attention inside)
+    with jax.named_scope("qkv"):
+        h = rmsnorm(block_params["norm_attn"], x)
     if block_table is not None:
         y, new_cache = attn_mod.paged_attention(
             block_params["attn"], h, positions, cfg=cfg, spec=spec,
@@ -206,17 +208,19 @@ def _apply_block(block_params, x, positions, *, cfg: ModelConfig,
     else:
         y, new_cache = attn_mod.attention(block_params["attn"], h, positions,
                                           cfg=cfg, spec=spec, cache=cache)
-    if cfg.post_norm:
-        y = rmsnorm(block_params["post_norm_attn"], y)
-    x = x + _barrier(y, cfg)
-    h = rmsnorm(block_params["norm_mlp"], x)
-    if spec.kind == "attn_moe":
-        y, aux = moe(block_params["moe"], h, cfg=cfg)
-    else:
-        y = mlp(block_params["mlp"], h)
-    if cfg.post_norm:
-        y = rmsnorm(block_params["post_norm_mlp"], y)
-    return x + _barrier(y, cfg), new_cache, aux
+    with jax.named_scope("attention"):
+        if cfg.post_norm:
+            y = rmsnorm(block_params["post_norm_attn"], y)
+        x = x + _barrier(y, cfg)
+    with jax.named_scope("mlp"):
+        h = rmsnorm(block_params["norm_mlp"], x)
+        if spec.kind == "attn_moe":
+            y, aux = moe(block_params["moe"], h, cfg=cfg)
+        else:
+            y = mlp(block_params["mlp"], h)
+        if cfg.post_norm:
+            y = rmsnorm(block_params["post_norm_mlp"], y)
+        return x + _barrier(y, cfg), new_cache, aux
 
 
 def _run_segment(seg_params, x, positions, *, cfg: ModelConfig, seg: Segment,
@@ -454,8 +458,13 @@ def paged_mixed_step(params, pools, block_tables, tokens, positions, row_ids,
     Every layer writes ALL packed K/V before attending, so a chunk token
     sees its same-dispatch predecessors AND any same-tick sibling's shared
     prefix blocks; the head runs only on the gathered boundary tokens, not
-    the full packed row.  Returns (logits, new pools)."""
-    x = _embed_inputs(params, tokens[None], cfg)              # (1, T, d)
+    the full packed row.  Returns (logits, new pools).
+
+    Its phases are named scopes in the ops' metadata: ``embed``, per layer
+    ``qkv``, ``kv_pool/write``, ``kv_pool/layout``, ``attention`` and
+    ``mlp``, then ``head``."""
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, tokens[None], cfg)          # (1, T, d)
     embeds0 = x
     new_pools = []
     for seg, seg_params, seg_pools in zip(cfg.layout(), params["segments"],
@@ -466,10 +475,11 @@ def paged_mixed_step(params, pools, block_tables, tokens, positions, row_ids,
                                  embeds0=embeds0, mode="mixed",
                                  block_table=block_tables, row_ids=row_ids)
         new_pools.append(np_)
-    xb = jnp.take(x[0], sample_idx, axis=0)          # (R, d) or (R, J, d)
-    if sample_idx.ndim == 1:
-        return _head(params, xb[None], cfg)[0], tuple(new_pools)
-    return _head(params, xb, cfg), tuple(new_pools)
+    with jax.named_scope("head"):
+        xb = jnp.take(x[0], sample_idx, axis=0)      # (R, d) or (R, J, d)
+        if sample_idx.ndim == 1:
+            return _head(params, xb[None], cfg)[0], tuple(new_pools)
+        return _head(params, xb, cfg), tuple(new_pools)
 
 
 def paged_decode_step(params, pools, block_tables, inputs, positions,

@@ -65,6 +65,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.devstore import DeviceStore
 from repro.core.dispatcher import LambdaHandle
@@ -75,9 +76,10 @@ from repro.core.store import CascadeStore, SpillPool, Worker
 from repro.models import supports_paged
 from repro.models.config import ModelConfig
 
-from .engine import ServeEngine
+from .engine import ATTN_COUNTERS, ServeEngine
 from .faults import InjectedFault, ReplicaCrashed
 from .scheduler import Request, Scheduler, virtual_deadline
+from .spans import COMPLETE, NODE_HEALTH
 
 # key = /serve/<model>/req/<session>/<request_id> → 5 components; hashing the
 # first 4 ("serve", model, "req", session) gives per-session affinity.
@@ -539,30 +541,32 @@ class ModelDeployment:
         completes — empty tokens at the normal key, and its reason under
         ``<request_id>/error`` so clients can tell refusal from a short
         generation (read it with ``error()``)."""
-        req.done_s = req.done_s or time.monotonic()
-        if self.spill_pool is not None:
-            # terminal state reached outside an engine (shed, failover
-            # failure): a preempted request's parked KV must not leak
-            self.spill_pool.discard(req.request_id)
-        for fn in list(self.on_done):
-            try:
-                fn(req)
-            except Exception:
-                # a listener failure (e.g. a cascade escalating into a
-                # stopped deployment) must not lose THIS request's answer:
-                # the response is still put and the completion still counted
-                # (the client sees the un-escalated result), and the drain
-                # can still finish.  Counted so operators can see it.
-                with self._lock:
-                    self.listener_errors += 1
-        if req.error is not None:
-            self.node.store.put(f"{self.out_prefix}/{req.request_id}/error",
-                                req.error)
-        self.node.store.put(f"{self.out_prefix}/{req.request_id}",
-                            np.asarray(req.tokens, np.int32))
-        with self._lock:
-            self.completed += 1
-        self.node._note_completed()
+        with TraceAnnotation(COMPLETE):
+            req.done_s = req.done_s or time.monotonic()
+            if self.spill_pool is not None:
+                # terminal state reached outside an engine (shed, failover
+                # failure): a preempted request's parked KV must not leak
+                self.spill_pool.discard(req.request_id)
+            for fn in list(self.on_done):
+                try:
+                    fn(req)
+                except Exception:
+                    # a listener failure (e.g. a cascade escalating into a
+                    # stopped deployment) must not lose THIS request's
+                    # answer: the response is still put and the completion
+                    # still counted (the client sees the un-escalated
+                    # result), and the drain can still finish.  Counted so
+                    # operators can see it.
+                    with self._lock:
+                        self.listener_errors += 1
+            if req.error is not None:
+                self.node.store.put(
+                    f"{self.out_prefix}/{req.request_id}/error", req.error)
+            self.node.store.put(f"{self.out_prefix}/{req.request_id}",
+                                np.asarray(req.tokens, np.int32))
+            with self._lock:
+                self.completed += 1
+            self.node._note_completed()
 
     # ------------------------------------------------------------- clients
     def submit(self, session_key: str, request_id: str, prompt: Any, *,
@@ -692,6 +696,9 @@ class ModelDeployment:
                                      for e in self.engines),
             "prefix_hits": sum(e.stats.prefix_hits for e in self.engines),
             "blocks_in_use": sum(e.stats.blocks_in_use for e in self.engines),
+            # the ragged kernel's block steps (EngineStats.attn_*)
+            **{k: sum(getattr(e.stats, k) for e in self.engines)
+               for k in ATTN_COUNTERS},
             # speculative decoding counters (0s when spec_k == 0; the rate
             # follows EngineStats.spec_acceptance_rate's convention — NaN
             # when nothing was drafted, distinct from "all rejected")
@@ -922,8 +929,9 @@ class ServeNode:
                         dep.mark_down(r, "crashed")
                         continue
                     busy += 1
-            dep.check_watchdog()
-            dep.sweep_down()
+            with TraceAnnotation(NODE_HEALTH):
+                dep.check_watchdog()
+                dep.sweep_down()
         return busy
 
     def _busy_report(self) -> str:

@@ -89,6 +89,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import (decode_step, paged_mixed_step, prefill,
                           sample_with_scores, speculative_verify,
@@ -100,6 +101,7 @@ from .draft import DraftSource, default_draft_source
 from .faults import ReplicaCrashed
 from .kvcache import CacheManager, PagedCacheManager, SpilledKV
 from .scheduler import Request, Scheduler, virtual_deadline
+from .spans import TICK_DISPATCH, TICK_EMIT, TICK_PACK, TICK_SYNC
 
 
 @dataclass
@@ -135,8 +137,17 @@ class EngineStats:
     resumes: int = 0               # preempted requests restored via adopt()
     #                                (replay-fallback resumes re-issue as
     #                                ordinary admissions and are NOT counted)
+    # the ragged kernel's grid, counted on the host from each tick's
+    # packing (per layer and kv head alike): T x max_blocks steps a tick;
+    # the steps its early-out lets into the body, as each lane walks the
+    # live blocks of the table row it clamps to; and the part of those
+    # that pad lanes walk (they clamp to row 0)
+    attn_grid_blocks: int = 0
+    attn_live_blocks: int = 0
+    attn_pad_blocks: int = 0
     ttft_s: list = field(default_factory=list)     # time to first token
-    tpot_s: list = field(default_factory=list)     # time per output token
+    # per finished request: (done - first token) / (tokens - 1)
+    tpot_s: list = field(default_factory=list)
     # per-SLO-class queue wait (issued_s - arrived_s), recorded once at a
     # request's FIRST issue (a preempted request keeps its original wait)
     queue_wait_s: dict = field(default_factory=dict)   # slo -> [seconds]
@@ -145,6 +156,37 @@ class EngineStats:
         """Fraction of drafted tokens the target model confirmed."""
         return (self.spec_accepted / self.spec_drafted
                 if self.spec_drafted else float("nan"))
+
+
+ATTN_COUNTERS = ("attn_grid_blocks", "attn_live_blocks", "attn_pad_blocks")
+
+
+def attention_grid(block_tables: np.ndarray, rows: np.ndarray
+                   ) -> tuple[int, int, int]:
+    """The ragged kernel's (grid, live, pad) block steps for one tick's
+    packing, per layer and kv head: every lane steps over all of the
+    table's columns; the early-out lets a lane into the body for the live
+    (non-negative, leading) entries of the row it clamps to, and a pad lane
+    (row -1) clamps to row 0 (``kernels.decode_attention.kernel``)."""
+    live = (block_tables >= 0).sum(axis=1)
+    per_lane = live[np.clip(rows, 0, len(block_tables) - 1)]
+    return (rows.size * block_tables.shape[1], int(per_lane.sum()),
+            int(per_lane[rows < 0].sum()))
+
+
+@dataclass
+class _Packed:
+    """One tick's host operands (``ServeEngine._pack_tick``)."""
+    toks: np.ndarray
+    pos: np.ndarray
+    rows: np.ndarray
+    sample_idx: np.ndarray
+    draft_toks: np.ndarray
+    draft_len: np.ndarray
+    block_tables: np.ndarray
+    decode_slots: list
+    finished: list
+    attn: dict                  # this tick's ATTN_COUNTERS
 
 
 class ServeEngine:
@@ -292,8 +334,9 @@ class ServeEngine:
                       if mesh is not None else contextlib.nullcontext()):
                     logits, pools = paged_mixed_step(p, pools, bt, toks, pos,
                                                      rows, sample_idx, cfg)
-                tok, n_acc, score = speculative_verify(logits, draft_toks,
-                                                       draft_len, seed, temp)
+                with jax.named_scope("sample"):
+                    tok, n_acc, score = speculative_verify(
+                        logits, draft_toks, draft_len, seed, temp)
                 return tok, n_acc, score, pools
 
             if mesh is not None:
@@ -346,6 +389,8 @@ class ServeEngine:
         if err is not None:
             self._reject(req, err)
             return
+        if req.enqueued_s is None:
+            req.enqueued_s = time.monotonic()
         self.scheduler.submit(req)
 
     def _validate(self, req: Request) -> str | None:
@@ -543,6 +588,11 @@ class ServeEngine:
 
     def _complete(self, req: Request) -> None:
         req.done_s = time.monotonic()
+        if (req.error is None and req.first_token_s is not None
+                and len(req.tokens) > 1):
+            # the client's time per output token: one entry per request
+            self.stats.tpot_s.append((req.done_s - req.first_token_s)
+                                     / (len(req.tokens) - 1))
         if self.spill_pool is not None:
             # a preempted request reaching ANY terminal state (done, expired
             # in queue, rejected) must not leak its parked KV
@@ -772,7 +822,26 @@ class ServeEngine:
     def _tick_mixed(self) -> int:
         """ONE fixed-shape mixed step: decode rows (each with up to spec_k
         verified draft tokens), + prefill chunks packed against the token
-        budget, one dispatch, one host sync."""
+        budget, one dispatch, one host sync.  Each phase is a host span
+        (``serving.spans``): pack, dispatch, sync, emit."""
+        with TraceAnnotation(TICK_PACK):
+            tick = self._pack_tick()
+        if tick is None:
+            return 0          # idle: nothing dispatched, not a tick
+        with TraceAnnotation(TICK_DISPATCH, **tick.attn):
+            out = self._dispatch_tick(tick)
+        with TraceAnnotation(TICK_SYNC):
+            # the ONE sync of this tick: tokens + accept counts + scores in
+            # one device_get — speculation amortizes it over every accepted
+            # token
+            host_toks, host_acc, host_scores = self._to_host(out)
+        with TraceAnnotation(TICK_EMIT):
+            return self._emit_tick(tick, host_toks, host_acc, host_scores)
+
+    def _pack_tick(self) -> _Packed | None:
+        """The tick's host operands: preemption, then steps 0-4 below, the
+        block table, and the attention-grid counts; None when nothing is
+        live or admissible."""
         if self.preempt:
             self._maybe_preempt()
         T = self.token_budget
@@ -834,37 +903,45 @@ class ServeEngine:
                 self.stats.spec_drafted += m
                 n += m
         if n == 0:
-            return 0          # idle: nothing dispatched, not a tick
-        t0 = time.monotonic()
-        bt = jnp.asarray(self.cm.block_tables())       # (n_slots, max_blocks)
+            return None
+        bt = self.cm.block_tables()                    # (n_slots, max_blocks)
+        attn = dict(zip(ATTN_COUNTERS, attention_grid(bt, rows)))
+        for k, v in attn.items():
+            setattr(self.stats, k, getattr(self.stats, k) + v)
+        return _Packed(toks, pos, rows, sample_idx, draft_toks, draft_len, bt,
+                       decode_slots, finished, attn)
+
+    def _dispatch_tick(self, tick: _Packed):
+        """Copy the operands in, run the jitted step, publish the pool."""
         sampled, n_acc, scores, pools = self._mixed(
-            self.params, self.cm.pools, bt, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(rows), jnp.asarray(sample_idx),
-            jnp.asarray(draft_toks), jnp.asarray(draft_len),
+            self.params, self.cm.pools, jnp.asarray(tick.block_tables),
+            jnp.asarray(tick.toks), jnp.asarray(tick.pos),
+            jnp.asarray(tick.rows), jnp.asarray(tick.sample_idx),
+            jnp.asarray(tick.draft_toks), jnp.asarray(tick.draft_len),
             self._next_seed())
         self.cm.pools = pools
         self.cm.publish()
         self.stats.blocks_in_use = self.cm.blocks_in_use
-        # the ONE sync of this tick: tokens + accept counts + scores in one
-        # device_get — speculation amortizes it over every accepted token
-        host_toks, host_acc, host_scores = self._to_host(
-            (sampled, n_acc, scores))
-        dt = time.monotonic() - t0
+        return sampled, n_acc, scores
+
+    def _emit_tick(self, tick: _Packed, host_toks, host_acc,
+                   host_scores) -> int:
+        """Hand the pulled tokens out: decode rows advance, finished
+        prompts emit their first token, done requests complete."""
         now = time.monotonic()
         n_emitted = 0
         # 4. decode rows advance: the accepted draft prefix plus the
         #    correction/bonus token all land this tick
-        for slot in decode_slots:
+        for slot in tick.decode_slots:
             req = self.live[slot]
             seq = self.cm.slots[slot]
-            m = int(draft_len[slot])
+            m = int(tick.draft_len[slot])
             a = int(host_acc[slot])
             n_emit = a + 1
             for j in range(n_emit):
                 req.tokens.append(int(host_toks[slot, j]))
                 req.scores.append(float(host_scores[slot, j, 0]))
                 req.entropies.append(float(host_scores[slot, j, 1]))
-                self.stats.tpot_s.append(dt / n_emit)
             self._last_host[slot] = int(host_toks[slot, a])
             seq.pos += n_emit
             self.stats.tokens_out += n_emit
@@ -882,14 +959,14 @@ class ServeEngine:
                 self._release_slot(slot, req)
                 self._complete(req)
         # 5. chunks that completed their prompt emit their first token
-        for slot in finished:
+        for slot in tick.finished:
             req = self.prefilling.pop(slot)
             tok = int(host_toks[slot, 0])
             self._last_host[slot] = tok
             n_emitted += 1
             self._emit_first_token(req, slot, tok, now, host_scores[slot, 0])
         self.stats.ticks += 1
-        if decode_slots:
+        if tick.decode_slots:
             self.stats.decode_ticks += 1
         return n_emitted
 
@@ -899,7 +976,6 @@ class ServeEngine:
         if not self.live:
             self.stats.ticks += 1
             return 0
-        t0 = time.monotonic()
         positions = self.cm.positions()[:, None]               # (B,1)
         active = self.cm.active_mask()
         new_toks, step_scores, self.cm.caches = self._step(
@@ -909,7 +985,6 @@ class ServeEngine:
         # the ONE sync of this tick: tokens + scores in one device_get
         host_toks, host_scores = self._to_host((new_toks, step_scores))
         self.cm.advance()
-        dt = time.monotonic() - t0
         done = []
         n_emitted = 0
         for slot, req in list(self.live.items()):
@@ -917,7 +992,6 @@ class ServeEngine:
             req.scores.append(float(host_scores[slot, 0]))
             req.entropies.append(float(host_scores[slot, 1]))
             n_emitted += 1
-            self.stats.tpot_s.append(dt)
             if len(req.tokens) >= req.max_new_tokens:
                 done.append(slot)
         for slot in done:

@@ -132,6 +132,10 @@ class Request:
     # ``tokens[replay_offset:]`` as generated — so a replayed request's
     # allocator footprint is exactly the uninterrupted request's.
     replay_offset: int = 0
+    # when the request first entered an engine's queue (ServeEngine.submit):
+    # enqueued_s - arrived_s is the upcall delivery, issued_s - enqueued_s
+    # the scheduler's wait
+    enqueued_s: float | None = None
     # when the request first issued into an engine (slot granted); queue
     # wait = issued_s - arrived_s feeds the per-SLO-class histograms
     issued_s: float | None = None

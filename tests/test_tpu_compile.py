@@ -127,6 +127,43 @@ def test_full_width_tick_compiles_for_one_chip(one_chip):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_tick_names_its_phases_and_its_kernel_for_one_chip(one_chip,
+                                                           kv_dtype):
+    """The tick's ops carry its phases as op_name scopes, the pool's
+    transposes under kv_pool/layout and the scatter under kv_pool/write,
+    and the kernel's custom call is named ragged_paged_attention, the name
+    the benchmark's trace reduction matches."""
+    import re
+
+    cfg = DANUBE.replace(n_layers=2, attn_backend="pallas")
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pools = _on(one_chip, jax.eval_shape(
+        lambda: init_paged_pools(cfg, 257, 16, kv_dtype=kv_dtype)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def tick(p, pools, bt, toks, pos, rows, sample_idx):
+        return paged_mixed_step(p, pools, bt, toks, pos, rows, sample_idx,
+                                cfg)
+
+    hlo = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, pools, i32(8, 32), i32(32), i32(32), i32(32),
+        i32(8)).compile().as_text()
+    ops = dict(re.findall(r'%([\w.-]+) = [^\n]*?op_name="([^"]*)"', hlo))
+    kernel = re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
+                        r'"tpu_custom_call"', hlo)
+    assert len(kernel) == 1
+    assert kernel[0].startswith("ragged_paged_attention")
+    assert "/attention/ragged_paged_attention/" in ops[kernel[0]]
+    transposes = [o for o in ops.values() if o.endswith("/transpose")
+                  and "/kv_pool/layout/" in o]
+    assert len(transposes) >= 2
+    for scope in ("/embed/", "/qkv/", "/kv_pool/write/", "/attention/",
+                  "/mlp/", "/head/"):
+        assert any(scope in o for o in ops.values()), scope
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_sharded_kernel_compiles_on_2x2_mesh(mesh, kv_dtype):
     """Under a mesh the kernel runs per shard through shard_map; without it
     Mosaic refuses to partition the call."""

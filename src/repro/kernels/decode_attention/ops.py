@@ -63,14 +63,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, q_pos, *,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
-                                             "dimension_semantics",
                                              "interpret"))
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, row_ids,
                            token_pos, *, k_scale=None, v_scale=None,
                            window: int | None = None,
                            softcap: float | None = None,
                            scale: float | None = None,
-                           dimension_semantics: tuple | None = None,
                            interpret: bool = False):
     """Mixed prefill-chunk + decode attention over a paged KV pool.
 
@@ -78,10 +76,13 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, row_ids,
     block_tables (R,nb) int32 physical block ids (-1 = unused); row_ids (T,)
     request row of each packed token (-1 = pad lane); token_pos (T,) absolute
     positions (-1 = pad lane).  One dispatch serves prefill chunks and decode
-    rows alike: every token streams its own request's blocks via a per-token
-    scalar-prefetched table gather and is causally masked at its own
-    position, so intra-chunk causality, cross-request isolation, and pad-lane
-    suppression are all the same mask.
+    rows alike: every token walks its own request's blocks, from the
+    scalar-prefetched table, in a loop bounded by what its mask can keep —
+    up to the block holding its position (causal), from the block holding
+    the window's oldest position, and not at all for a pad lane — copying
+    several pages of all kv heads at once; intra-chunk causality,
+    cross-request isolation, and pad-lane suppression are all the same
+    mask.
 
     Multi-token VERIFY rows (speculative decoding) are the same packing: a
     row that feeds k tokens at consecutive tail positions [P, P+k) is
@@ -93,15 +94,14 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, row_ids,
     with ``row_ids = arange(B)``).
 
     ``k_scale``/``v_scale`` (num_blocks, block_size, K) f32 mark the pool
-    as quantized; ``dimension_semantics`` is the real-TPU tuning hook (nb
-    must stay sequential — see kernel.DEFAULT_DIMENSION_SEMANTICS)."""
+    as quantized.  How many pages one copy covers is derived from the
+    shapes (``kernel.pages_per_group``); there is nothing to tune."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return ragged_paged_attention_fwd(q, k_pool, v_pool, block_tables,
                                       row_ids, token_pos, scale=scale,
                                       softcap=softcap, window=window,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      dimension_semantics=dimension_semantics,
                                       interpret=interpret)
 
 

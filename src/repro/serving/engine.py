@@ -83,6 +83,7 @@ from __future__ import annotations
 
 import contextlib
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -91,6 +92,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.decode_attention.kernel import (lane_bounds,
+                                                   pages_per_group)
 from repro.models import (decode_step, paged_mixed_step, prefill,
                           sample_with_scores, speculative_verify,
                           supports_paged, supports_speculative)
@@ -137,11 +140,13 @@ class EngineStats:
     resumes: int = 0               # preempted requests restored via adopt()
     #                                (replay-fallback resumes re-issue as
     #                                ordinary admissions and are NOT counted)
-    # the ragged kernel's grid, counted on the host from each tick's
-    # packing (per layer and kv head alike): T x max_blocks steps a tick;
-    # the steps its early-out lets into the body, as each lane walks the
-    # live blocks of the table row it clamps to; and the part of those
-    # that pad lanes walk (they clamp to row 0)
+    # the ragged kernel's work, counted on the host from each tick's
+    # packing and summed over its attention layers.  Its grid steps are
+    # lanes x layers; each lane walks the blocks [first, last) its
+    # mask can keep (causal, windowed) in copy groups of P pages.  Grid:
+    # the block slots those groups cover, each lane's last group rounded
+    # up to P; live: the blocks the kernel streams, sum(last - first);
+    # pad: the part of those that pad lanes walk, which is none
     attn_grid_blocks: int = 0
     attn_live_blocks: int = 0
     attn_pad_blocks: int = 0
@@ -161,17 +166,21 @@ class EngineStats:
 ATTN_COUNTERS = ("attn_grid_blocks", "attn_live_blocks", "attn_pad_blocks")
 
 
-def attention_grid(block_tables: np.ndarray, rows: np.ndarray
-                   ) -> tuple[int, int, int]:
-    """The ragged kernel's (grid, live, pad) block steps for one tick's
-    packing, per layer and kv head: every lane steps over all of the
-    table's columns; the early-out lets a lane into the body for the live
-    (non-negative, leading) entries of the row it clamps to, and a pad lane
-    (row -1) clamps to row 0 (``kernels.decode_attention.kernel``)."""
+def attention_grid(block_tables: np.ndarray, rows: np.ndarray,
+                   pos: np.ndarray, *, block_size: int, pages: int,
+                   window: int | None = None) -> tuple[int, int, int]:
+    """The ragged kernel's (grid, live, pad) block counts for one layer's
+    call on one tick's packing, as ``kernels.decode_attention.kernel``
+    walks it: each lane streams the logical blocks ``lane_bounds`` gives
+    it, in groups of ``pages``; a pad lane (row or position -1) walks
+    none."""
     live = (block_tables >= 0).sum(axis=1)
-    per_lane = live[np.clip(rows, 0, len(block_tables) - 1)]
-    return (rows.size * block_tables.shape[1], int(per_lane.sum()),
-            int(per_lane[rows < 0].sum()))
+    qp = np.where(rows < 0, -1, pos)
+    first, last = lane_bounds(qp, live[np.clip(rows, 0, len(live) - 1)],
+                              block_size, window, xp=np)
+    n = np.maximum(last - first, 0)
+    return (int((-(-n // pages) * pages).sum()), int(n.sum()),
+            int(n[qp < 0].sum()))
 
 
 @dataclass
@@ -247,6 +256,19 @@ class ServeEngine:
                     f"token_budget={self.token_budget} < n_slots={n_slots}: "
                     f"every live decode row costs one token per tick, so a "
                     f"smaller budget would starve decodes")
+            # what the attention-grid counters mirror: the kernel's pages
+            # per copy group for the kv heads one call sees (a mesh slice's
+            # shard), and how many layers attend under each window
+            pool = self.cm.pools[0][0]
+            K = cfg.n_kv_heads
+            m = 1 if mesh is None else dict(mesh.shape).get("model", 1)
+            self._attn_pages = pages_per_group(
+                block_size, K // m if K % m == 0 else K, cfg.head_dim,
+                pool["k"].dtype.itemsize, "k_scale" in pool)
+            self._attn_windows = Counter()
+            for seg in cfg.layout():
+                for spec in seg.pattern:
+                    self._attn_windows[spec.window] += seg.repeat
         else:
             from repro.kernels.decode_attention.quant import is_quantized
             if is_quantized(kv_dtype):
@@ -905,7 +927,11 @@ class ServeEngine:
         if n == 0:
             return None
         bt = self.cm.block_tables()                    # (n_slots, max_blocks)
-        attn = dict(zip(ATTN_COUNTERS, attention_grid(bt, rows)))
+        counts = [np.multiply(layers, attention_grid(
+            bt, rows, pos, block_size=self.cm.block_size,
+            pages=self._attn_pages, window=window))
+            for window, layers in self._attn_windows.items()]
+        attn = dict(zip(ATTN_COUNTERS, np.sum(counts, axis=0).tolist()))
         for k, v in attn.items():
             setattr(self.stats, k, getattr(self.stats, k) + v)
         return _Packed(toks, pos, rows, sample_idx, draft_toks, draft_len, bt,

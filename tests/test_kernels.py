@@ -183,24 +183,37 @@ RAGGED_SWEEP = [
     (2, 2, 128, 32, 2, ((50, 11), (33, 1)), 12, None),          # windowed
     (8, 8, 32, 16, 4, ((1, 1), (2, 1), (64, 64)), None, None),  # tiny ctxs
     (4, 1, 64, 64, 2, ((100, 36), (128, 1), (90, 2)), 20, 50.0),
+    # the kernel's trip bounds, at bs 16 where a copy group is P = 8 pages:
+    # a row of exactly P live blocks, and one of P + 1
+    (4, 2, 32, 16, 10, ((128, 1), (140, 3), (20, 1)), None, None),
+    # a chunk whose table already holds blocks past its tokens (a third
+    # entry: tokens the table holds): each token stops at its own block
+    (4, 2, 32, 8, 8, ((30, 12, 60), (9, 1, 40)), None, None),
+    # a window whose first visible block is past block 0
+    (8, 2, 32, 16, 14, ((200, 5), (150, 1)), 40, None),
+    # the benchmark's head sizes: head_dim 80 (K = 8, and K = 2 as one
+    # shard of a 2x2 slice sees it) and 120
+    (32, 8, 80, 16, 12, ((180, 6), (33, 1), (1, 1)), 64, None),
+    (8, 2, 80, 16, 9, ((140, 9), (16, 1)), None, None),
+    (32, 8, 120, 16, 10, ((150, 4), (128, 1)), None, 30.0),
 ]
 
 
 @pytest.mark.parametrize("H,K,D,bs,nb,reqs,win,cap", RAGGED_SWEEP)
 def test_ragged_paged_attention_matches_refs(H, K, D, bs, nb, reqs, win, cap):
     rng = np.random.default_rng(H * 100 + bs + len(reqs))
-    ctxs = [c for c, _ in reqs]
-    N = 1 + sum(-(-c // bs) for c in ctxs) + 2          # null + used + spare
+    held = [r[2] if len(r) > 2 else r[0] for r in reqs]  # in the table
+    N = 1 + sum(-(-c // bs) for c in held) + 2          # null + used + spare
     ks = jax.random.split(jax.random.PRNGKey(H + bs), 3)
-    T = sum(ch for _, ch in reqs) + 3                   # 3 pad lanes
+    T = sum(r[1] for r in reqs) + 3                     # 3 pad lanes
     q = jax.random.normal(ks[0], (T, H, D), jnp.float32)
     kp = jax.random.normal(ks[1], (N, bs, K, D), jnp.float32)
     vp = jax.random.normal(ks[2], (N, bs, K, D), jnp.float32)
-    bt = jnp.asarray(_random_block_tables(rng, N, bs, nb, ctxs))
+    bt = jnp.asarray(_random_block_tables(rng, N, bs, nb, held))
     rows = np.full(T, -1, np.int32)
     tpos = np.full(T, -1, np.int32)
     n = 0
-    for r, (ctx, chunk) in enumerate(reqs):
+    for r, (ctx, chunk, *_) in enumerate(reqs):
         rows[n:n + chunk] = r
         tpos[n:n + chunk] = np.arange(ctx - chunk, ctx)
         n += chunk
@@ -236,6 +249,8 @@ VERIFY_SWEEP = [
     (2, 2, 64, 32, ((50, 3), (33, 5), (9, 2), (64, 1)), 12, None),
     (8, 2, 32, 8, ((25, 5), (63, 3), (7, 2), (5, 1), (30, 12)), 16, 50.0),
     (4, 1, 64, 64, ((100, 5), (128, 2), (90, 3)), None, None),
+    # head_dim 120, a verify row whose tail crosses a copy group of P = 8
+    (8, 2, 120, 16, ((150, 4), (40, 3), (128, 1)), None, None),
 ]
 
 
@@ -436,6 +451,8 @@ QUANT_CASES = [
     (16, 3, ((33, 33), (40, 1), (17, 1)), None, 30.0),
     (32, 2, ((50, 11), (33, 1)), 12, None),
     (64, 2, ((100, 4), (90, 1)), 20, 50.0),
+    # rows of P + 1 and P blocks of 16 (P = 8), a window past block 0
+    (16, 10, ((140, 3), (128, 1)), 40, None),
 ]
 
 
@@ -550,10 +567,9 @@ def test_quantize_kv_roundtrip_properties():
 
 
 def test_ragged_early_out_padding_invariance():
-    """The per-token num_blocks early-out must be EXACT: widening the block
-    tables with extra -1 columns (a larger nb grid whose tail every token
-    skips) and mixing rows of very different lengths must be bit-identical
-    to the tight layout."""
+    """Each token's trip bound must be EXACT: widening the block tables
+    with extra -1 columns (a tail no token walks) and mixing rows of very
+    different lengths must be bit-identical to the tight layout."""
     H, K, D, bs = 4, 2, 64, 8
     ctxs = (60, 3, 17)
     rng = np.random.default_rng(3)
@@ -590,3 +606,22 @@ def test_suggest_block_size_monotone():
         assert bs >= prev
         prev = bs
     assert suggest_block_size(128, 8, vmem_budget_bytes=1 << 24) == 512
+
+
+def test_pages_per_group_covers_128_tokens_within_its_budget():
+    """A copy group holds at least 128 KV tokens where its double-buffered
+    pages fit the VMEM budget, fewer where they would not, and never none;
+    head_dim counts as its 128-lane padding."""
+    from repro.kernels.decode_attention.kernel import (GROUP_VMEM_BYTES,
+                                                       pages_per_group)
+    assert pages_per_group(16, 8, 80, 2, False) == 8
+    assert pages_per_group(16, 8, 120, 2, False) == 8
+    assert pages_per_group(16, 2, 80, 1, True) == 8
+    assert pages_per_group(128, 8, 128, 2, False) == 1
+    assert pages_per_group(8, 1, 64, 4, False) == 16
+    assert pages_per_group(16, 8, 80, 2, False) == pages_per_group(
+        16, 8, 128, 2, False)
+    big = pages_per_group(16, 16, 512, 4, True)
+    assert 1 < big < 8
+    assert 2 * 2 * 16 * 16 * (512 * 4 + 512) * big <= GROUP_VMEM_BYTES
+    assert pages_per_group(16, 64, 1024, 4, True) == 1

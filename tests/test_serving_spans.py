@@ -95,33 +95,68 @@ def test_each_tick_phase_is_one_span_per_tick(params, tmp_path):
 
 
 def test_attention_grid_counts_a_hand_packed_table():
+    """Blocks of 4, copy groups of 2 pages.  Row 0 has 3 live blocks, row
+    1 one, row 2 none; row 0's lanes sit at positions 5 and 10, row 1's
+    at 0-2, row 2's at 0, and two pad lanes carry no position."""
     bt = np.array([[5, 6, 7, -1], [8, -1, -1, -1], [-1, -1, -1, -1]],
                   np.int32)
     rows = np.array([0, 0, 1, 1, 1, -1, -1, 2], np.int32)
-    # lanes walk 3, 3, 1, 1, 1, then two pads on row 0 (3 each), then 0
-    assert attention_grid(bt, rows) == (8 * 4, 3 + 3 + 1 + 1 + 1 + 6, 6)
+    pos = np.array([5, 10, 0, 1, 2, -1, -1, 0], np.int32)
+    # causal: position 5 walks 2 blocks, 10 walks 3; row 1's lanes 1 each;
+    # pads and the empty row nothing; groups round 2, 3, 1, 1, 1 up to 2
+    assert attention_grid(bt, rows, pos, block_size=4, pages=2) == (
+        2 + 4 + 2 + 2 + 2, 2 + 3 + 1 + 1 + 1, 0)
+    # a window of 6 starts position 10 at block (10 - 6 + 1) // 4 = 1
+    assert attention_grid(bt, rows, pos, block_size=4, pages=2,
+                          window=6) == (2 + 2 + 2 + 2 + 2, 2 + 2 + 1 + 1 + 1,
+                                        0)
+
+
+def test_a_pad_lane_walks_nothing():
+    """A lane with no row walks nothing even where it carries a position,
+    and so does a lane at position -1 on a live row: the counters count
+    no block for either, and the kernel writes exact zeros there."""
+    from repro.kernels.decode_attention.ops import ragged_paged_attention
+
+    bt = np.array([[3, 1, 2, -1]], np.int32)
+    rows = np.array([0, -1, 0, -1], np.int32)
+    pos = np.array([9, 9, -1, -1], np.int32)
+    assert attention_grid(bt, rows, pos, block_size=4, pages=2) == (4, 3, 0)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (4, 4, 8), np.float32)
+    kp = jax.random.normal(ks[1], (4, 4, 2, 8), np.float32)
+    vp = jax.random.normal(ks[2], (4, 4, 2, 8), np.float32)
+    out = np.asarray(ragged_paged_attention(q, kp, vp, bt, rows, pos,
+                                            interpret=True))
+    assert np.all(out[1:] == 0) and np.all(out[0] != 0)
 
 
 def test_a_tick_of_decode_rows_a_chunk_and_pads_counts_by_hand(params):
-    """Blocks of 4, 8 columns, 16 lanes.  Tick 1 packs A's 10-token prompt
-    (slot 0, 3 blocks) and B's 3 (slot 1, 1 block) with 3 pad lanes;
-    tick 2 packs A's and B's decode rows and C's 9-token prompt (slot 2,
-    3 blocks) with 5 pads.  Pads clamp to row 0, A's."""
+    """Blocks of 4, 8 columns, 16 lanes, 2 layers; at these shapes the
+    kernel copies P = 32 pages a group, so each lane's group rounds up to
+    32 slots.  Tick 1 packs A's 10-token prompt (slot 0, 3 blocks) and B's
+    3 (slot 1, 1 block) with 3 pad lanes; tick 2 packs A's and B's decode
+    rows and C's 9-token prompt (slot 2, 3 blocks) with 5 pads.  Each
+    token walks only the blocks up to its own position; pads walk none."""
     eng = ServeEngine(CFG, params, n_slots=4, max_len=32, paged=True,
                       block_size=4, token_budget=16, prefix_cache=False)
+    assert eng._attn_pages == 32 and eng._attn_windows == {None: 2}
     eng.submit(_req("A", 10, seed=1))
     eng.submit(_req("B", 3, seed=2))
     eng.tick()
     st = eng.stats
     assert [len(eng.cm.slots[s].table) for s in (0, 1)] == [3, 1]
+    # A's positions 0-9 walk 1, 1, 1, 1, 2, 2, 2, 2, 3, 3 blocks; B's 0-2
+    # one each
     assert (st.attn_grid_blocks, st.attn_live_blocks,
-            st.attn_pad_blocks) == (16 * 8, 10 * 3 + 3 * 1 + 3 * 3, 3 * 3)
+            st.attn_pad_blocks) == (2 * 13 * 32, 2 * (18 + 3), 0)
     eng.submit(_req("C", 9, seed=3))
     eng.tick()
     assert [len(eng.cm.slots[s].table) for s in (0, 1, 2)] == [3, 1, 3]
+    # A decodes at position 10 (3 blocks), B at 3 (1), C's 0-8 walk 15
     assert (st.attn_grid_blocks, st.attn_live_blocks,
             st.attn_pad_blocks) == (
-        2 * 16 * 8, 42 + (3 + 1 + 9 * 3 + 5 * 3), 9 + 5 * 3)
+        2 * 13 * 32 + 2 * 11 * 32, 2 * 21 + 2 * (3 + 1 + 15), 0)
     assert st.host_syncs == st.ticks == 2
 
 
@@ -153,9 +188,9 @@ def test_enqueue_stamp_splits_delivery_from_the_scheduler_wait(params):
     assert len(got) == 4
     for r in got:
         assert r.arrived_s <= r.enqueued_s <= r.issued_s <= r.first_token_s
-    for k in ("attn_grid_blocks", "attn_live_blocks", "attn_pad_blocks"):
+    for k in ("attn_grid_blocks", "attn_live_blocks"):
         assert st[k] > 0
-    assert st["attn_pad_blocks"] <= st["attn_live_blocks"] \
+    assert 0 == st["attn_pad_blocks"] < st["attn_live_blocks"] \
         <= st["attn_grid_blocks"]
 
 

@@ -12,6 +12,7 @@ these compiles: an entry written for a described chip cannot be read back
 without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -210,3 +211,53 @@ def test_query_heads_line_up_with_their_kv_heads_per_shard(mesh):
         assert len(kv_heads) == cfg.n_kv_heads // 4
         assert sorted({h // G for h in heads}) == list(kv_heads)
         assert len(heads) == G * len(kv_heads)
+
+
+def _computations(hlo: str) -> dict:
+    """HLO text split into {computation name: its body's text}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return {n: "\n".join(body) for n, body in comps.items()}
+
+
+def test_tick_runs_one_kernel_call_per_layer(one_chip):
+    """The walk over each lane's blocks happens inside the kernel: the
+    compiled tick of a 3-layer h2o-danube-1.8b holds one
+    ragged_paged_attention call under the attention scope, in the body of
+    the layer loop, which runs 3 times."""
+    cfg = DANUBE.replace(n_layers=3, attn_backend="pallas")
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pools = _on(one_chip, jax.eval_shape(
+        lambda: init_paged_pools(cfg, 257, 16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def tick(p, pools, bt, toks, pos, rows, sample_idx):
+        return paged_mixed_step(p, pools, bt, toks, pos, rows, sample_idx,
+                                cfg)
+
+    hlo = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, pools, i32(8, 32), i32(32), i32(32), i32(32),
+        i32(8)).compile().as_text()
+    comps = _computations(hlo)
+    calls = [(n, line) for n, body in comps.items()
+             for line in body.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    body, line = calls[0]
+    assert re.search(r"%ragged_paged_attention[\w.]* = ", line)
+    assert "/attention/ragged_paged_attention/" in line
+    loops = re.findall(r"while\([^\n]*condition=%([\w.-]+), body=%"
+                       + re.escape(body) + r"\b", hlo)
+    assert len(loops) == 1
+    cond = comps[loops[0]]
+    bound = re.search(r"%([\w.-]+) = s32\[\][^\n]* constant\((\d+)\)", cond)
+    assert bound and bound.group(2) == "3"
+    assert re.search(r"compare\([^\n]*%" + re.escape(bound.group(1))
+                     + r"\)[^\n]*direction=LT", cond)

@@ -27,7 +27,7 @@ from jax.profiler import TraceAnnotation
 
 from . import correct, spec, trace
 from .drive import Drive, pct
-from .model import check_layout, dims, make_weights, program_config
+from .model import check_layout, make_weights
 from .peaks import peak
 from .traffic import Mix
 
@@ -51,6 +51,30 @@ class CompileCounter:
     def between(self, a: float, b: float) -> int:
         with self._lock:
             return sum(a <= t < b for t in self.times)
+
+
+class GcPauses:
+    """Python's garbage collections while it is open: when each began, how
+    long it held the interpreter, and its generation."""
+
+    def __init__(self):
+        self.pauses: list[tuple[float, float, int]] = []
+        self._t = None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t = time.monotonic()
+        elif self._t is not None:
+            self.pauses.append((self._t, time.monotonic() - self._t,
+                                info["generation"]))
+            self._t = None
+
+    def close(self) -> None:
+        gc.callbacks.remove(self._on)
+
+    def between(self, a: float, b: float) -> list[tuple[float, float, int]]:
+        return [p for p in self.pauses if a <= p[0] < b]
 
 
 def use_compile_cache(path: Path) -> None:
@@ -117,6 +141,7 @@ class Served:
     token_budget: int
     trace_dir: str | None
     compiles_in_window: int
+    gc_in_window: list
 
 
 def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
@@ -127,12 +152,14 @@ def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
 
     arch = cell.config
     dep_cfg = arch["deployment"]
+    vocab = cell.model.dims(arch)["V"]
     t_ready = time.monotonic()
     node = ServeNode(n_workers=1)
     kw = {k: dep_cfg[k] for k in ("num_blocks", "kv_dtype",
                                   "devices_per_replica")
           if dep_cfg.get(k) is not None}
-    dep = node.deploy(cell.name, program_config(cell.config_name, arch),
+    dep = node.deploy(cell.name,
+                      cell.model.program_config(cell.config_name, arch),
                       params, n_replicas=1, n_slots=dep_cfg["n_slots"],
                       max_len=dep_cfg["max_len"], **kw)
     engine = dep.engines[0]
@@ -145,17 +172,18 @@ def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
     warm = np.random.default_rng(np.random.SeedSequence([int(seed) % 2**128,
                                                          3]))
     dep.submit("warmup", "warmup",
-               warm.integers(0, dims(arch)["V"], 48, dtype=np.int32),
+               warm.integers(0, vocab, 48, dtype=np.int32),
                max_new_tokens=2)
     node.run_until_drained(timeout_s=1200)
     t_compiled = time.monotonic()
 
-    mix = Mix(cell.traffic, seed, vocab=dims(arch)["V"],
+    mix = Mix(cell.traffic, seed, vocab=vocab,
               max_len=dep_cfg["max_len"])
     drive = Drive(node, dep, mix, warm_s=cell.traffic["warm_s"],
                   window_s=seconds)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
     tracing = False
+    gcs = GcPauses()
     drive.start()
     try:
         if traced:
@@ -172,6 +200,7 @@ def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
             tracing = False
     finally:
         drive.stop()
+        gcs.close()
         if tracing:
             jax.profiler.stop_trace()
     served = Served(
@@ -181,7 +210,8 @@ def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
                "warm_s": drive.open_s - t_compiled},
         mem_peak=peak_bytes(devices), devices=devices,
         token_budget=engine.token_budget, trace_dir=trace_dir,
-        compiles_in_window=compiles.between(drive.open_s, drive.close_s))
+        compiles_in_window=compiles.between(drive.open_s, drive.close_s),
+        gc_in_window=gcs.between(drive.open_s, drive.close_s))
     # the program's state goes before the reference runs; the weights are
     # the benchmark's own and stay
     node.undeploy(cell.name)
@@ -192,9 +222,21 @@ def serve(cell: spec.Cell, params, seed: int, seconds: float, traced: bool,
     return served
 
 
+def context(cell: spec.Cell, served: Served, device_kind: str) -> Context:
+    """What the readers read of one run's window (no trace yet)."""
+    d = served.drive
+    return Context(cell=cell, arch=cell.config, drive=d,
+                   setup_s=served.setup_s, window_s=d.close_s - d.open_s,
+                   token_budget=served.token_budget,
+                   chips=len(served.devices), device_kind=device_kind,
+                   trace=None)
+
+
 def report(served: Served) -> None:
-    """The earlier lines: set-up split, sample counts, lateness,
-    compilations inside the window, peak memory."""
+    """The earlier lines: set-up split, sample counts, lateness, the
+    longest step and garbage collections inside the window (a stall of the
+    whole process shows in all three), compilations inside the window, peak
+    memory."""
     d = served.drive
     due, done = d.in_window(), d.finished_in_window()
     late = [r.sent - r.due for r in due if r.sent is not None]
@@ -208,17 +250,27 @@ def report(served: Served) -> None:
     if late:
         log(f"generator lateness: max {max(late) * 1e3:.3f} ms, p95 "
             f"{pct(late, 95) * 1e3:.3f} ms over {len(late)} sends")
+    t, dt = d.longest_step
+    log(f"longest node.step inside the window: {dt * 1e3:.3f} ms at "
+        f"+{(t or d.open_s) - d.open_s:.3f} s")
+    pauses = served.gc_in_window
+    if pauses:
+        t, dt, _ = max(pauses, key=lambda p: p[1])
+        log(f"gc inside the window: {len(pauses)} collections "
+            f"({sum(p[2] == 2 for p in pauses)} of generation 2), "
+            f"{sum(p[1] for p in pauses) * 1e3:.3f} ms in all, longest "
+            f"{dt * 1e3:.3f} ms at +{t - d.open_s:.3f} s")
     log(f"compilations inside the window: {served.compiles_in_window}")
     log(f"peak_bytes_in_use: {served.mem_peak}")
 
 
 def build(cell: spec.Cell, seed: int):
-    """The seed's weights, made on the device, checked against the
-    program's layout."""
+    """The seed's weights in the layout of the cell's model module, made on
+    the device, checked against the program's layout."""
     arch = cell.config
-    params = make_weights(arch, seed)
+    params = make_weights(cell.model.weight_shapes(arch), seed)
     jax.block_until_ready(params)
-    check_layout(params, program_config(cell.config_name, arch))
+    check_layout(params, cell.model.program_config(cell.config_name, arch))
     return params
 
 
@@ -259,11 +311,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     log(f"compared {sum(len(r.req.tokens) for r in sample)} served tokens "
         f"of {len(sample)} requests with the reference in {t_ref:.3f} s")
 
-    ctx = Context(cell=cell, arch=arch, drive=drive, setup_s=served.setup_s,
-                  window_s=drive.close_s - drive.open_s,
-                  token_budget=served.token_budget,
-                  chips=len(served.devices), device_kind=info["kind"],
-                  trace=None)
+    ctx = context(cell, served, info["kind"])
     breakdown = None
     if traced:
         files = glob.glob(f"{served.trace_dir}/plugins/profile/*/*.xplane.pb")

@@ -69,6 +69,7 @@ class Drive:
         self.open_s = self.close_s = None
         self.counters_open = self.counters_close = None
         self.busy_step_s = 0.0               # busy node.step() time in window
+        self.longest_step = (None, 0.0)      # (start, seconds) in window
         self.errors: list[BaseException] = []
         dep.on_done.append(self._on_done)
 
@@ -122,10 +123,13 @@ class Drive:
             t = time.monotonic()
             with TraceAnnotation("bench.node_step"):
                 busy = self.node.step()
-            if busy:
-                if self.open_s is not None and self.close_s is None:
-                    self.busy_step_s += time.monotonic() - t
-            else:
+            if self.open_s is not None and self.close_s is None:
+                dt = time.monotonic() - t
+                if busy:
+                    self.busy_step_s += dt
+                if dt > self.longest_step[1]:
+                    self.longest_step = (t, dt)
+            if not busy:
                 with TraceAnnotation("bench.step_wait"):
                     time.sleep(0.0005)
 

@@ -1,11 +1,37 @@
-"""A configuration file's model, as the program takes it, and its weights.
+"""A configuration's weights and its parameter layout, made the same way
+for every model.
 
-The configuration files hold the model's published config (Hugging Face
-key names).  ``program_config`` maps them onto the program's ``ModelConfig``;
-``make_weights`` builds random weights from the seed, on the device, in one
-jitted call, in the dtype they are served in and in the program's parameter
-layout.  The weights are the benchmark's, not the program's: the plain
-reference reads the same arrays.
+What belongs to one architecture lives in its model module,
+``<bench>/models/<model>.py``, which the configuration file names under
+``"model"`` (beside ``"reference"``, its plain reference) and
+``spec.load_cell`` loads as ``Cell.model``.  The module provides:
+
+- ``dims(arch) -> dict``: the sizes the rest of the benchmark reads by
+  short name; at least ``V``, the vocabulary the traffic draws its token
+  ids from, and whatever the module's own functions read;
+- ``program_config(name, arch)``: the program's ``ModelConfig``;
+- ``weight_shapes(arch)``: a tree of ``(shape, dtype, fan_in)`` leaves in
+  the program's parameter layout (``fan_in`` None: a norm scale);
+- ``matmul_flops(arch, lanes, emitted) -> float``: the matrix-product
+  operations of ``lanes`` packed tokens and ``emitted`` head rows, which
+  ``mfu`` divides by the chip's peak.  An expert layer counts the experts
+  a token is routed to and any shared expert, never every expert held.
+
+Where a configuration holds one chip's share of a stated deployment (its
+experts, or a slice of its vocabulary), the count held here is the value
+under its own key, the key is listed under ``reduced`` in
+``BENCHMARK.json``, and the file states the cut beside it under
+``"cut": {key: {"published": ..., "deployment": ...}}``; ``spec``
+refuses a ``reduced`` key the file does not state so.  ``matmul_flops``
+then counts what this chip computes: of the experts a token is routed
+to, the share held here.  The module is also where a configuration keeps
+the operation and byte counts of its own kernels, for a roofline reader
+to import.
+
+Here: ``make_weights`` builds random weights from the seed, on the device,
+in one jitted call, in the dtype they are served in; ``check_layout``
+holds them to the program's own initializer.  The weights are the
+benchmark's, not the program's: the plain reference reads the same arrays.
 """
 from __future__ import annotations
 
@@ -13,72 +39,19 @@ import jax
 import jax.numpy as jnp
 
 
-def dims(arch: dict) -> dict:
-    """The sizes every other part of the benchmark reads, by short name."""
-    d, h = arch["hidden_size"], arch["num_attention_heads"]
-    return {"d": d, "H": h, "K": arch["num_key_value_heads"],
-            "D": arch.get("head_dim") or d // h,
-            "F": arch["intermediate_size"], "L": arch["num_hidden_layers"],
-            "V": arch["vocab_size"], "window": arch.get("sliding_window"),
-            "theta": float(arch["rope_theta"]),
-            "eps": float(arch["rms_norm_eps"]),
-            "tied": bool(arch["tie_word_embeddings"]),
-            "dtype": arch["torch_dtype"]}
-
-
-def program_config(name: str, arch: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    m = dims(arch)
-    return ModelConfig(
-        name=name, family="dense", n_layers=m["L"], d_model=m["d"],
-        n_heads=m["H"], n_kv_heads=m["K"], head_dim=m["D"], d_ff=m["F"],
-        vocab_size=m["V"], tie_embeddings=m["tied"], window=m["window"],
-        rope_theta=m["theta"], dtype=m["dtype"],
-        max_target_length=arch.get("max_position_embeddings", 4096))
-
-
-def weight_shapes(arch: dict) -> dict:
-    """(shape, dtype, fan_in) of every leaf, in the program's layout: the
-    layers stacked on a leading axis, attention weights as (d, heads, D),
-    norm scales zero-centred (the norm multiplies by 1 + scale) in f32."""
-    m = dims(arch)
-    d, H, K, D, F, L, V = (m[k] for k in "dHKDFLV")
-    dt = jnp.dtype(m["dtype"])
-    f32 = jnp.float32
-    tree = {
-        # a tied table is the output head too: scaled as the head is
-        "embed": {"table": ((V, d), dt, d if m["tied"] else 1)},
-        "final_norm": {"scale": ((d,), f32, None)},
-        "segments": (({
-            "norm_attn": {"scale": ((L, d), f32, None)},
-            "attn": {"wq": ((L, d, H, D), dt, d), "wk": ((L, d, K, D), dt, d),
-                     "wv": ((L, d, K, D), dt, d),
-                     "wo": ((L, H, D, d), dt, H * D)},
-            "norm_mlp": {"scale": ((L, d), f32, None)},
-            "mlp": {"w_gate": ((L, d, F), dt, d), "w_up": ((L, d, F), dt, d),
-                    "w_down": ((L, F, d), dt, F)},
-        },),),
-    }
-    if not m["tied"]:
-        tree["head"] = {"table": ((V, d), dt, d)}
-    return tree
-
-
 def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], tuple)
 
 
-def make_weights(arch: dict, seed: int):
-    """Random weights from ``seed``, made on the device in one jitted call.
+def make_weights(shapes, seed: int):
+    """Random weights of the tree ``shapes`` (a model module's
+    ``weight_shapes``) from ``seed``, made on the device in one jitted call.
 
     Matrices are N(0, 1/fan_in), so activations stay O(1) through every
     layer; norm scales are N(0, 0.1), so the norms' weights take part in
     every comparison.  The output head (the embedding table, when tied) is
     scaled to fan-in as well: logits then have a spread of about 1, where a
     wrong block, head or position moves the greedy token."""
-    shapes = weight_shapes(arch)
     leaves, treedef = jax.tree.flatten(shapes, is_leaf=_is_leaf)
 
     @jax.jit

@@ -7,7 +7,6 @@ Shares are in percent.
 from __future__ import annotations
 
 from .drive import censored, pct
-from .flops import matmul_flops
 
 # The ragged paged-attention kernel as the trace names its events: the
 # Pallas kernel's function name, which the compiled custom call carries.
@@ -57,7 +56,10 @@ def lane_occupancy(ctx):
 
 
 def mfu(ctx):
-    flops = matmul_flops(ctx.arch, lanes(ctx), ctx.delta("tokens_out"))
+    """The window's matrix-product operations, as the cell's model module
+    counts them, over the chips' peak for the window."""
+    flops = ctx.cell.model.matmul_flops(ctx.arch, lanes(ctx),
+                                        ctx.delta("tokens_out"))
     peak = ctx.peak()["bf16_flops_per_s"]
     return 100.0 * flops / (ctx.window_s * ctx.chips * peak)
 

@@ -39,7 +39,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 def tick_hlo(cell, params) -> str:
     """The compiled tick's HLO text, from a deployment made as the run's
     was (``cell_run.serve``)."""
-    from bench.harness.model import program_config
     from repro.serving.cluster import ServeNode
 
     dep_cfg = cell.config["deployment"]
@@ -48,7 +47,8 @@ def tick_hlo(cell, params) -> str:
           if dep_cfg.get(k) is not None}
     node = ServeNode(n_workers=1)
     try:
-        dep = node.deploy(cell.name, program_config(cell.config_name,
+        dep = node.deploy(cell.name,
+                          cell.model.program_config(cell.config_name,
                                                     cell.config),
                           params, n_replicas=1, n_slots=dep_cfg["n_slots"],
                           max_len=dep_cfg["max_len"], **kw)

@@ -6,7 +6,8 @@ optional sliding window, the SwiGLU MLP, a final RMSNorm and the output head.
 No cache, no kernel, no batching of requests into one row: every sequence is
 its own row, attended causally from position 0.
 
-It reads the benchmark's weights (``bench/harness/model.py`` layout: layers
+It reads the benchmark's weights in the layout of the configuration's
+model module (``bench/models/dense_gqa.py``, ``weight_shapes``: layers
 stacked on the leading axis, norm scales zero-centred so a norm multiplies
 by 1 + scale) and upcasts them to float32 one layer at a time, inside the
 layer's computation, so float32 copies of the whole model never exist.

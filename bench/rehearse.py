@@ -31,19 +31,20 @@ def rehearse(cell, topo, n_slots=None, slice4=False) -> dict:
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                               SingleDeviceSharding)
 
-    from bench.harness.model import make_weights, program_config
+    from bench.harness.model import make_weights
     from repro.launch.sharding import kv_pool_shardings, param_shardings
     from repro.models import init_paged_pools, paged_mixed_step
     from repro.models.sampling import speculative_verify
 
     arch, dep = cell.config, cell.config["deployment"]
-    cfg = program_config(cell.config_name, arch).replace(
+    cfg = cell.model.program_config(cell.config_name, arch).replace(
         attn_backend="pallas")
     n, max_len, bs = n_slots or dep["n_slots"], dep["max_len"], 16
     nb = -(-max_len // bs)
     num_blocks = dep["num_blocks"] or 1 + (n + 2) * nb     # engine default
     T = max(32, 2 * n)                                     # engine default
-    params = jax.eval_shape(lambda: make_weights(arch, 0))
+    params = jax.eval_shape(
+        lambda: make_weights(cell.model.weight_shapes(arch), 0))
     pools = jax.eval_shape(lambda: init_paged_pools(
         cfg, num_blocks, bs, kv_dtype=dep["kv_dtype"]))
     if slice4:

@@ -6,17 +6,21 @@
 One process, one set of weights; for each session rate the cell's own
 set-up, warm phase and a window of ``--seconds``, then one JSON line: the
 rate of requests due, TTFT p95 and median from the due time, the median
-TTFT of the requests due in each half of the window, the requests
-completed, and the requests outstanding (due and not yet answered, in the
-queue or in a slot) at the window's open and close.  An outstanding count
-or a TTFT that grows through the window means the rate is past what the
-system sustains: a rate is sustained when the count at the close is at
-most 2 above the open's, and
-the second half's median TTFT is at most 1.5 times the first half's plus
-1 s.  Rates run in the order given, and the sweep stops at the first rate
-that is not sustained; its last line names the knee, the highest sustained
-rate, and that first rate above it.  The benchmark's own runs do not run
-this; the chosen rate is written into the mix file.
+TTFT of the requests due in each half of the window, the queue wait's and
+TPOT's p95, the lane occupancy, the tokens per second and the tick (each as
+the cell's metric readers read it), the requests completed, and the
+backlog: the requests outstanding (due and not yet answered, in the queue
+or in a slot), at the window's open and close and as a mean over each half
+of the window (read every 0.25 s from the records after the run).  A
+backlog or a TTFT that grows through the window means the rate is past
+what the system sustains: a rate is sustained when the second half's mean
+backlog is at most 2 above the first half's, and the second half's median
+TTFT is at most 1.5 times the first half's plus 1 s.  (The counts at the
+open and the close alone are two instants, each off by the few requests in
+service.)  Rates run in the order given, and the sweep stops at the first
+rate that is not sustained; its last line names the knee, the highest
+sustained rate, and that first rate above it.  The benchmark's own runs do
+not run this; the chosen rate is written into the mix file.
 """
 import argparse
 import dataclasses
@@ -38,10 +42,20 @@ def outstanding(records, t: float) -> int:
                     or r.req.done_s > t))
 
 
+def backlog_halves(records, a: float, b: float, step: float = 0.25) -> list:
+    """The mean of ``outstanding`` over each half of [a, b), read every
+    ``step`` seconds."""
+    mid = (a + b) / 2
+    return [sum(outstanding(records, t) for t in ts) / len(ts)
+            for ts in ([a + i * step for i in range(int((mid - a) / step))],
+                       [mid + i * step for i in range(int((b - mid) / step))])]
+
+
 def sustained(row: dict) -> bool:
     """The rule above, on one rate's line."""
     a, b = row["ttft_p50_halves_s"]
-    return (row["outstanding_close"] <= row["outstanding_open"] + 2
+    first, second = row["backlog_halves"]
+    return (second <= first + 2
             and a is not None and b is not None and b <= 1.5 * a + 1.0)
 
 
@@ -55,7 +69,7 @@ def main() -> int:
 
     import jax
 
-    from bench.harness import cell_run, spec
+    from bench.harness import cell_run, readers, spec
     from bench.harness.drive import censored, pct
 
     cell = spec.load_cell(ROOT, args.workload)
@@ -65,6 +79,8 @@ def main() -> int:
     cell_run.use_compile_cache(cell.bench_dir / ".jax_cache")
     compiles = cell_run.CompileCounter()
     params = cell_run.build(cell, args.seed)
+    kind = jax.devices()[0].device_kind
+
     knee = above = None
     for rate in args.rates:
         traffic = json.loads(json.dumps(cell.traffic))
@@ -72,6 +88,7 @@ def main() -> int:
         c = dataclasses.replace(cell, traffic=traffic)
         served = cell_run.serve(c, params, args.seed, args.seconds, False,
                                 t_proc0=time.monotonic(), compiles=compiles)
+        ctx = cell_run.context(c, served, kind)
         d = served.drive
         due = d.in_window()
         ttft = [censored(r.req.first_token_s if r.req is not None else None,
@@ -82,15 +99,19 @@ def main() -> int:
                   for first in (True, False)]
         row = {
             "rate_sessions_per_s": rate,
-            "requests_due_per_s": len(due) / (d.close_s - d.open_s),
-            "ttft_p95_s": pct(ttft, 95) if ttft else None,
+            "requests_due_per_s": len(due) / ctx.window_s,
+            "ttft_p95_s": readers.ttft_p95(ctx),
             "ttft_p50_s": pct(ttft, 50) if ttft else None,
             "ttft_p50_halves_s": [pct(h, 50) if h else None for h in halves],
+            "queue_wait_p95_s": readers.queue_wait_p95(ctx),
+            "tpot_p95_s": readers.tpot_p95(ctx),
+            "lane_occupancy": readers.lane_occupancy(ctx),
             "completed": len(d.finished_in_window()),
             "outstanding_open": outstanding(recs, d.open_s),
             "outstanding_close": outstanding(recs, d.close_s),
-            "tokens_per_s": d.delta("tokens_out") / (d.close_s - d.open_s),
-            "tick_ms": 1e3 * d.busy_step_s / max(1, d.delta("ticks"))}
+            "backlog_halves": backlog_halves(recs, d.open_s, d.close_s),
+            "tokens_per_s": readers.tokens_per_s(ctx),
+            "tick_ms": readers.tick_ms(ctx)}
         row["sustained"] = sustained(row)
         print(json.dumps(row), flush=True)
         if not row["sustained"]:

@@ -1,12 +1,16 @@
 """The plain reference computes what the program's own float32 forward
-computes, at the registry's SMOKE sizes, and the fp8 control does not."""
+computes, at the registry's SMOKE sizes (and, for the expert layer kept
+with the tests, at the tiny attn_moe size), and the fp8 control does not."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.harness.model import make_weights, program_config
+from bench.harness.model import make_weights
+from bench.harness.spec import _load_module
+from bench.models import dense_gqa
 from bench.reference import danube
+from bench.tests.tiny import TESTS, TINY_MOE_ARCH
 from repro.configs.registry import get_config
 from repro.models import forward
 
@@ -23,16 +27,22 @@ def smoke_arch(arch_id: str) -> dict:
             "torch_dtype": "float32", "max_position_embeddings": 64}
 
 
-@pytest.mark.parametrize("arch_id", ["h2o-danube-1.8b", "h2o-danube-3-4b"])
+@pytest.mark.parametrize("arch_id", ["h2o-danube-1.8b", "h2o-danube-3-4b",
+                                     "attn_moe"])
 def test_reference_matches_the_programs_forward(arch_id):
-    arch = smoke_arch(arch_id)
-    cfg = program_config(arch_id, arch).replace(attn_backend="xla")
-    params = make_weights(arch, 3)
+    if arch_id == "attn_moe":
+        arch = TINY_MOE_ARCH
+        mod = _load_module(TESTS / "models" / "attn_moe.py")
+        ref_mod = _load_module(TESTS / "reference" / "attn_moe.py")
+    else:
+        arch, mod, ref_mod = smoke_arch(arch_id), dense_gqa, danube
+    cfg = mod.program_config(arch_id, arch).replace(attn_backend="xla")
+    params = make_weights(mod.weight_shapes(arch), 3)
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, arch["vocab_size"], n, dtype=np.int32)
             for n in (40, 23)]
     want = [np.arange(len(s)) for s in seqs]
-    ref = danube.logits(params, arch, seqs, want)
+    ref = ref_mod.logits(params, arch, seqs, want)
     for s, r in zip(seqs, ref):
         toks = jnp.asarray(s)[None]
         pos = jnp.arange(len(s), dtype=jnp.int32)[None]
@@ -44,7 +54,7 @@ def test_reference_matches_the_programs_forward(arch_id):
 def test_control_reads_wider_gaps_than_the_reference_itself():
     arch = smoke_arch("h2o-danube-1.8b")
     arch = dict(arch, hidden_size=128, intermediate_size=256, head_dim=32)
-    params = make_weights(arch, 5)
+    params = make_weights(dense_gqa.weight_shapes(arch), 5)
     rng = np.random.default_rng(1)
     seqs = [rng.integers(0, arch["vocab_size"], 48, dtype=np.int32)]
     want = [np.arange(48)]

@@ -8,21 +8,34 @@ import time
 from pathlib import Path
 from types import SimpleNamespace
 
+import pytest
 
 from bench.harness import cell_run, correct, spec
 from bench.harness.cell_run import run_cell
-from bench.tests.tiny import make_root
+from bench.tests.tiny import TINY_ARCH, TINY_MOE_ARCH, make_root
 
 ROOT = Path(__file__).resolve().parents[2]
 
 
-def run(tmp_path, seed=2**31 + 3):
-    cell = spec.load_cell(make_root(tmp_path), "tiny.chat")
+# the tiny cell as its dense configuration, and as one of another family
+# that brings its own model module and reference
+ARCHS = pytest.mark.parametrize("arch", [TINY_ARCH, TINY_MOE_ARCH],
+                                ids=["dense_gqa", "attn_moe"])
+
+
+def run(tmp_path, seed=2**31 + 3, arch=TINY_ARCH):
+    cell = spec.load_cell(make_root(tmp_path, arch=arch), "tiny.chat")
     return run_cell(cell, seed, 2.0, False, t_proc0=time.monotonic())
 
 
-def test_tiny_cell_runs_correct(tmp_path):
-    out = run(tmp_path)
+@ARCHS
+def test_tiny_cell_runs_correct(tmp_path, capsys, arch):
+    out = run(tmp_path, arch=arch)
+    err = capsys.readouterr().err.splitlines()
+    assert any(line.startswith("longest node.step inside the window: ")
+               for line in err)
+    assert [line.split(":")[0] for line in err[-2:]] == [
+        "check max_logit_gap", "check failed_requests"]
     assert out["correct"] is True
     assert out["attempted"] > 5 and out["failed"] == 0
     assert set(out["metrics"]) == {"ttft_p95_s", "setup_s"}
@@ -69,7 +82,8 @@ def test_the_sample_spans_several_requests_and_holds_the_longest():
     assert correct.sample([], 7, min_tokens=1, min_requests=6) == []
 
 
-def test_an_altered_token_is_not_correct(tmp_path, monkeypatch):
+@ARCHS
+def test_an_altered_token_is_not_correct(tmp_path, monkeypatch, arch):
     import repro.serving.engine as engine
 
     real = engine.speculative_verify
@@ -79,7 +93,7 @@ def test_an_altered_token_is_not_correct(tmp_path, monkeypatch):
         return (tok + 1) % logits.shape[-1], n_acc, score
 
     monkeypatch.setattr(engine, "speculative_verify", altered)
-    out = run(tmp_path)
+    out = run(tmp_path, arch=arch)
     assert out["correct"] is False
     assert out["checks"]["max_logit_gap"]["value"] > 1e-3
 

@@ -1,12 +1,16 @@
 """A benchmark root in a temporary directory with one tiny cell, built from
-files alone: the tests run the harness on it on the CPU."""
+files alone: the tests run the harness on it on the CPU.  Its model module
+and reference are the benchmark's own, or ones kept with these tests
+(``models/``, ``reference/`` here): a configuration of another family
+comes in as new files, with nothing of the harness changed."""
 from __future__ import annotations
 
 import json
 import shutil
 from pathlib import Path
 
-BENCH = Path(__file__).resolve().parents[1]
+TESTS = Path(__file__).resolve().parent
+BENCH = TESTS.parent
 
 TINY_ARCH = {
     "source": "tiny danube-shaped block for CPU tests",
@@ -15,11 +19,18 @@ TINY_ARCH = {
     "vocab_size": 256, "sliding_window": 64, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-05, "max_position_embeddings": 256,
     "tie_word_embeddings": False, "torch_dtype": "float32",
-    "reference": "danube",
+    "model": "dense_gqa", "reference": "danube",
     "deployment": {"n_slots": 4, "max_len": 192, "num_blocks": None,
                    "kv_dtype": None, "devices_per_replica": None},
     "correct": {"max_logit_gap": 1e-3},
 }
+
+# the program's family "moe": every layer attention and a top-2 mixture of
+# 4 experts with one shared expert
+TINY_MOE_ARCH = dict(
+    TINY_ARCH, source="tiny attn_moe block for CPU tests",
+    num_local_experts=4, num_experts_per_tok=2, n_shared_experts=1,
+    moe_intermediate_size=48, model="attn_moe", reference="attn_moe")
 
 TINY_MIX = {
     "pool": 32, "span": 4,
@@ -42,19 +53,28 @@ def read(ctx):
 '''
 
 
+def _source(sub: str, name: str) -> Path:
+    """``<sub>/<name>.py`` of the benchmark, else of these tests."""
+    for d in (BENCH, TESTS):
+        if (d / sub / f"{name}.py").is_file():
+            return d / sub / f"{name}.py"
+    raise FileNotFoundError(f"no {sub}/{name}.py")
+
+
 def make_root(tmp: Path, arch: dict = TINY_ARCH, mix: dict = TINY_MIX) -> Path:
     """A checkout-like root: BENCHMARK.json and ``tiny/`` with a config, a
-    mix, a metric reader and a copy of the reference."""
+    mix, a metric reader and copies of the model module and reference the
+    config names."""
     d = tmp / "tiny"
-    for sub in ("configs", "traffic", "metrics", "reference"):
+    for sub in ("configs", "traffic", "metrics", "models", "reference"):
         (d / sub).mkdir(parents=True, exist_ok=True)
     (d / "configs" / "tiny-danube.json").write_text(json.dumps(arch))
     (d / "traffic" / "tiny_chat.json").write_text(json.dumps(mix))
     (d / "metrics" / "tokens_per_tick.tiny.py").write_text(TINY_METRIC)
     for name in ("ttft_p95_s.py", "setup_s.py"):
         shutil.copy(BENCH / "metrics" / name, d / "metrics" / name)
-    shutil.copy(BENCH / "reference" / "danube.py",
-                d / "reference" / "danube.py")
+    for sub, key in (("models", "model"), ("reference", "reference")):
+        shutil.copy(_source(sub, arch[key]), d / sub / f"{arch[key]}.py")
     spec = {
         "command": ["python3", "bench/run.py"], "paths": ["tiny"],
         "run_seconds": 2,
